@@ -17,6 +17,7 @@ import textwrap
 
 _CODE = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU drill: never the parent's chip
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp

@@ -39,6 +39,7 @@ def fig11_time_vs_datasize(sizes=(1000, 4000, 16000)):
 
 _SCALING = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU drill: never the parent's chip
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp

@@ -164,7 +164,7 @@ def run():
         "derived": f"{SHAPE},blocks={N_BLOCKS},fused_route_hist,prefetch=2",
     })
 
-    # Resilience rows (see PERF.md "Resilience"): what per-level
+    # Resilience rows: what per-level
     # checkpointing costs over the resident while_loop engine, what a
     # crash resume costs (restore + the remaining levels), and what a
     # 5%-fault feed under bounded retry costs over the clean stream.

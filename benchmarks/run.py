@@ -37,6 +37,9 @@ def main(argv=None) -> None:
     )
     args = parser.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     all_rows = []
     suites = [
         ("accuracy", "accuracy (Figs. 8-9)", bench_accuracy.run),

@@ -43,6 +43,9 @@ def run_multiproc_worker(args):
         f"127.0.0.1:{args.port}", args.multiproc, args.worker,
         local_device_count=args.local_devices,
     )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import numpy as np
 
     from repro.core import ForestConfig
@@ -100,6 +103,8 @@ def run_multiproc(args):
              "--rows", str(args.rows), "--features", str(args.features),
              "--trees", str(args.trees)],
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+            # A CPU drill: the workers never compete for an accelerator.
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         for i in range(args.multiproc)
     ]
@@ -158,8 +163,11 @@ def main():
         fit_bins_sharded, make_prf_train_fn, predict_sharded,
     )
     from repro.data.tabular import make_classification, train_test_split
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.roofline.analysis import analyze_hlo_text
+
+    enable_compile_cache()
 
     x, y = make_classification(n_samples=4096, n_features=64, n_classes=4, seed=1)
     xtr, ytr, xte, yte = train_test_split(x, y, 0.25, 0)

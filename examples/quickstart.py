@@ -25,6 +25,9 @@ def main():
     from repro.core import ForestConfig, train_prf
     from repro.core.baselines import train_mlrf_like, train_rf
     from repro.data.tabular import make_classification, train_test_split
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print(f"dataset: N={args.samples} M={args.features} (high-dim, noisy)")
     x, y = make_classification(

@@ -41,19 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: `jax.shard_map(check_vma=)` on
-    >= 0.6, `jax.experimental.shard_map.shard_map(check_rep=)` before."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
 from .dsi import bootstrap_counts
 from .engine import (
     CollectivePlane, _gather_feature_bins, _safe_mean, finalize_forest, grow,
@@ -70,18 +57,11 @@ from .histograms import class_channels, level_histograms, regression_channels
 from .types import Forest, ForestConfig
 
 
-def _axis_size(a: str) -> int:
-    """`jax.lax.axis_size` compat (absent before jax 0.5): psum of the
-    literal 1 over a named axis constant-folds to the axis size."""
-    fn = getattr(jax.lax, "axis_size", None)
-    return fn(a) if fn is not None else jax.lax.psum(1, a)
-
-
 def _multi_axis_index(axes: Sequence[str]) -> jnp.ndarray:
     """Linearized index over possibly-multiple mesh axes (row-major)."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -153,11 +133,11 @@ class MeshPlane(CollectivePlane):
         self.use_rs = (
             config.hist_reduce == "psum_scatter"
             and len(self.sample_axes) == 1
-            and Fl % _axis_size(self.sample_axes[0]) == 0
+            and Fl % jax.lax.axis_size(self.sample_axes[0]) == 0
         )
         if self.use_rs:
             self.didx = jax.lax.axis_index(self.sample_axes[0])
-            self.fl_sub = Fl // _axis_size(self.sample_axes[0])
+            self.fl_sub = Fl // jax.lax.axis_size(self.sample_axes[0])
             mask_src = (
                 mask_loc if mask_loc is not None
                 else jnp.ones((config.n_trees, Fl), jnp.bool_)
@@ -314,10 +294,11 @@ def grow_sharded_checkpointed(
             st.hist_cache
 
     state_specs = (P(), P(), P(None, sample_axes), P(), P(), cache_specs)
-    init_fn = jax.jit(_shard_map(
+    init_fn = jax.jit(jax.shard_map(
         init_kernel, mesh=mesh,
         in_specs=(P(sample_axes), P(None, sample_axes), P(None, feature_axis)),
         out_specs=state_specs,
+        check_vma=False,
     ))
 
     def step_kernel(xb_loc, base_loc, w_loc, mask_loc, forest, slot_node,
@@ -333,13 +314,14 @@ def grow_sharded_checkpointed(
         return st.forest, st.slot_node, st.sample_slot, st.rng, st.level, \
             st.hist_cache
 
-    step_fn = jax.jit(_shard_map(
+    step_fn = jax.jit(jax.shard_map(
         step_kernel, mesh=mesh,
         in_specs=(
             P(sample_axes, feature_axis), P(sample_axes),
             P(None, sample_axes), P(None, feature_axis),
         ) + state_specs,
         out_specs=state_specs,
+        check_vma=False,
     ))
 
     state = init_fn(base_dev, w_dev, mask_dev)
@@ -592,15 +574,17 @@ def grow_forest_streamed_sharded(
     data_specs = (hist_spec, P(sample_axes, feature_axis), P(sample_axes),
                   P(None, sample_axes), P(None, sample_axes), P())
     sr_specs = (P(),) if reuse else ()
-    step_route = jax.jit(_shard_map(
+    step_route = jax.jit(jax.shard_map(
         step_kernel_route, mesh=mesh,
         in_specs=data_specs + (P(), P()) + sr_specs,
         out_specs=(hist_spec, P(None, sample_axes)),
+        check_vma=False,
     ))
-    step_first = jax.jit(_shard_map(
+    step_first = jax.jit(jax.shard_map(
         step_kernel_first, mesh=mesh,
         in_specs=data_specs + sr_specs,
         out_specs=(hist_spec, P(None, sample_axes)),
+        check_vma=False,
     ))
 
     split_be = resolve_split_backend(config.split_backend)
@@ -672,16 +656,18 @@ def grow_forest_streamed_sharded(
             )
 
         if reuse:
-            return jax.jit(_shard_map(
+            return jax.jit(jax.shard_map(
                 plan_kernel_reuse, mesh=mesh,
                 in_specs=(hist_spec, P(), P(), P(), P(None, feature_axis),
                           cache_specs),
                 out_specs=(P(), P(), P(), P(), cache_specs),
+                check_vma=False,
             ))
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             plan_kernel, mesh=mesh,
             in_specs=(hist_spec, P(), P(), P(), P(None, feature_axis)),
             out_specs=(P(), P(), P(), P()),
+            check_vma=False,
         ))
 
     plan_init, plan_next = make_plan(True), make_plan(False)
@@ -802,8 +788,9 @@ def grow_forest_streamed_sharded(
                 hist_c = plane.combine_hist(hist_part[0])
                 return hist_c[:, 0, 0].sum(axis=1)
 
-            root_fn = jax.jit(_shard_map(
+            root_fn = jax.jit(jax.shard_map(
                 root_kernel, mesh=mesh, in_specs=(hist_spec,), out_specs=P(),
+                check_vma=False,
             ))
             # Host round-trip: the replicated root counts fetch cleanly
             # on every process, and the .at[].set below then runs on
@@ -924,11 +911,12 @@ def oob_accuracy_streamed_sharded(
         total = jax.lax.psum(jnp.sum(oob, 1), sample_axes)
         return correct, total
 
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P(sample_axes, feature_axis), P(sample_axes),
                   P(None, sample_axes), P(sample_axes)),
         out_specs=(P(), P()),
+        check_vma=False,
     ))
 
     k = w_np.shape[0]
@@ -1013,11 +1001,12 @@ def predict_streamed_sharded(
         [_pad_rows(np.asarray(b), p) for b, p in zip(blocks, pads)],
         placement=x_sh, prefetch=prefetch, **(feeder_opts or {}),
     )
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         partial(_vote_labels_kernel, forest, feature_axis=feature_axis),
         mesh=mesh,
         in_specs=(P(sample_axes, feature_axis),),
         out_specs=P(sample_axes),
+        check_vma=False,
     ))
     try:
         out = [
@@ -1154,11 +1143,12 @@ def make_prf_train_fn(
                 forest = dataclasses.replace(forest, tree_weight=w)
             return forest
 
-        return _shard_map(
+        return jax.shard_map(
             kernel,
             mesh=mesh,
             in_specs=(x_spec, y_spec, P()),
             out_specs=P(),
+            check_vma=False,
         )(x_binned, y, key)
 
     in_shardings = (
@@ -1191,11 +1181,12 @@ def predict_sharded(forest: Forest, x_binned, mesh, *,
                     sample_axes=("data",), feature_axis="model"):
     """Distributed weighted-voting prediction (Eq. 10). Returns [N] labels."""
     sample_axes = tuple(sample_axes)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_vote_labels_kernel, forest, feature_axis=feature_axis),
         mesh=mesh,
         in_specs=(P(sample_axes, feature_axis),),
         out_specs=P(sample_axes),
+        check_vma=False,
     )
     return jax.jit(fn)(x_binned)
 
@@ -1319,10 +1310,11 @@ def fit_bins_sharded(
         )
         if runtime is not None else jnp.asarray(payloads)
     )
-    gathered = jax.jit(_shard_map(
+    gathered = jax.jit(jax.shard_map(
         _exchange, mesh=mesh,
         in_specs=(P(axes),),
         out_specs=P(),
+        check_vma=False,
     ))(p_dev)
     gathered = np.ascontiguousarray(np.asarray(jax.device_get(gathered)))
 
@@ -1399,11 +1391,12 @@ def _dimred_streamed_multiproc(
         )
         return h[None]
 
-    acc = jax.jit(_shard_map(
+    acc = jax.jit(jax.shard_map(
         acc_kernel, mesh=mesh,
         in_specs=(hist_spec, P(sample_axes, feature_axis), P(sample_axes),
                   P(None, sample_axes)),
         out_specs=hist_spec,
+        check_vma=False,
     ))
 
     def final_kernel(hist_part):
@@ -1411,8 +1404,9 @@ def _dimred_streamed_multiproc(
         gr = multiway_gain_ratio(h[:, 0])                # [k, Fl]
         return jax.lax.all_gather(gr, feature_axis, axis=1, tiled=True)
 
-    final = jax.jit(_shard_map(
+    final = jax.jit(jax.shard_map(
         final_kernel, mesh=mesh, in_specs=(hist_spec,), out_specs=P(),
+        check_vma=False,
     ))
 
     hist = runtime.zeros((D, k, 1, F, B, C), hist_spec, jnp.float32)

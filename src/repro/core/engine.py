@@ -63,6 +63,7 @@ from .histograms import (
     sibling_perm,
     sibling_segments,
 )
+from ..kernels import default_interpret
 from .types import Forest, ForestConfig, GrowthState
 
 
@@ -227,15 +228,14 @@ def fused_level_scores(
     N, F = x_binned.shape
     S, B = config.frontier, config.n_bins
     C = base_channels.shape[-1]
-    packed = config.packed_hist and not config.regression
-    W = hist_feature_slab(N, F, S, B, C, packed=packed)
+    W = hist_feature_slab(N, F, S, B, C)
     Fp = _round_up(F, W)
     xb = jnp.pad(x_binned, ((0, 0), (0, Fp - F)))
     mask = (
         feature_mask if feature_mask is not None else jnp.ones((tc, F), jnp.bool_)
     )
     mask = jnp.pad(mask, ((0, 0), (0, Fp - F)))   # padded features masked out
-    interpret = jax.default_backend() != "tpu"
+    interpret = default_interpret()
 
     def slab(j, carry):
         f0 = j * W
@@ -394,11 +394,10 @@ def fused_reuse_level_scores(
     N, F = x_binned.shape
     S, B, R = config.frontier, config.n_bins, config.max_splits_per_level
     C = base_channels.shape[-1]
-    packed = config.packed_hist and not config.regression
     # Off-path slab width (sized for S rows) keeps split_scan_block's
     # geometry — and therefore its running-best carry arithmetic —
     # identical to the reuse=off trace.
-    W = hist_feature_slab(N, F, S, B, C, packed=packed)
+    W = hist_feature_slab(N, F, S, B, C)
     Fp = _round_up(F, W)
     xb = jnp.pad(x_binned, ((0, 0), (0, Fp - F)))
     mask = (
@@ -406,7 +405,7 @@ def fused_reuse_level_scores(
     )
     mask = jnp.pad(mask, ((0, 0), (0, Fp - F)))
     cache_h = jnp.pad(cache["hist"], ((0, 0), (0, 0), (0, Fp - F)) + ((0, 0),) * 2)
-    interpret = jax.default_backend() != "tpu"
+    interpret = default_interpret()
 
     def slab(j, acc):
         carry, h2 = acc

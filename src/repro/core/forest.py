@@ -118,6 +118,22 @@ def predict_proba_trees(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
     return counts / jnp.maximum(counts.sum(-1, keepdims=True), 1e-38)
 
 
+def predict_label_trees(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
+    """Per-tree predicted class ``argmax_c h_i(x)``. Returns [k, N] int32.
+
+    The argmax is taken per pool node and then gathered at the leaves,
+    which equals the argmax of ``predict_proba_trees`` without ever
+    building the ``[k, N, C]`` tensor (on a TPU its small class axis
+    pads to a full lane tile, 64x at C=2).
+    """
+    leaves = route_to_leaves(forest, x_binned)
+    counts = forest.class_counts
+    probs = counts / jnp.maximum(counts.sum(-1, keepdims=True), 1e-38)
+    return jnp.take_along_axis(
+        jnp.argmax(probs, axis=-1).astype(jnp.int32), leaves, axis=1
+    )
+
+
 def predict_value_trees(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
     """Per-tree regression outputs h_i(x). Returns [k, N]."""
     leaves = route_to_leaves(forest, x_binned)
@@ -143,7 +159,8 @@ def fused_vote_scores(
     size — including a non-divisible final remainder — gives the same
     scores.
     """
-    from ..kernels.tree_traverse.kernel import default_interpret, traverse_block
+    from ..kernels import default_interpret
+    from ..kernels.tree_traverse.kernel import traverse_block
 
     k = forest.feature.shape[0]
     config = forest.config

@@ -61,30 +61,36 @@ class SplitScores(NamedTuple):
     right_counts: jnp.ndarray  # [k, S, C] class counts of right child
 
 
-def split_gain_ratios_from_cumsum(cum: jnp.ndarray, total: jnp.ndarray) -> jnp.ndarray:
+def split_gain_ratios_from_cumsum(
+    left: jnp.ndarray, total: jnp.ndarray, *, axis: int = -1
+) -> jnp.ndarray:
     """Eq. (2)-(6) from bin prefix sums — shared by the XLA and Pallas
-    split backends so their gain ratios are bit-identical.
+    split backends so their gain ratios are computed by one formula.
 
     Args:
-      cum:   [..., F, B, C] ``cumsum(hist, axis=-2)``.
-      total: [..., F, C] node class counts (``cum[..., -1, :]``).
+      left:  bin prefix sums without the last bin: ``[..., F, B-1, C]``
+             in the XLA layout (the ``cumsum`` sliced on the bin axis).
+      total: node class counts, broadcastable against ``left`` along the
+             bin axis (``[..., F, 1, C]``).
+      axis:  the class axis of both (the split-scan kernel keeps it
+             leading, ``[C, B-1, S]``).
     Returns:
-      gr: [..., F, B-1]; invalid (empty-side) splits get -inf.
+      gr with the class axis reduced away (``[..., F, B-1]``); invalid
+      (empty-side) splits get -inf.
     """
-    n = total.sum(axis=-1)                          # [..., F]
-    h_node = entropy_from_counts(total)             # [..., F]  Entropy(S_i), Eq. 2
+    n = total.sum(axis=axis)                        # [..., F, 1]
+    h_node = entropy_from_counts(total, axis=axis)  # Entropy(S_i), Eq. 2
 
-    left = cum[..., :-1, :]                         # [..., F, B-1, C]
-    right = total[..., None, :] - left              # [..., F, B-1, C]
-    n_l = left.sum(-1)                              # [..., F, B-1]
-    n_r = right.sum(-1)
-    n_tot = jnp.maximum(n[..., None], 1e-38)
+    right = total - left                            # [..., F, B-1, C]
+    n_l = left.sum(axis=axis)                       # [..., F, B-1]
+    n_r = right.sum(axis=axis)
+    n_tot = jnp.maximum(n, 1e-38)
 
     # Eq. (3): conditional entropy of the target given the split.
-    h_cond = (n_l / n_tot) * entropy_from_counts(left) + (
+    h_cond = (n_l / n_tot) * entropy_from_counts(left, axis=axis) + (
         n_r / n_tot
-    ) * entropy_from_counts(right)
-    gain = h_node[..., None] - h_cond               # Eq. (5)
+    ) * entropy_from_counts(right, axis=axis)
+    gain = h_node - h_cond                          # Eq. (5)
 
     # Eq. (4): self-split information of the binary partition.
     p_l = n_l / n_tot
@@ -96,6 +102,11 @@ def split_gain_ratios_from_cumsum(cum: jnp.ndarray, total: jnp.ndarray) -> jnp.n
     return jnp.where(valid, gr, -jnp.inf)
 
 
+def left_total(cum: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(left, total)`` scorer operands of a ``[..., B, C]`` cumsum."""
+    return cum[..., :-1, :], cum[..., -1:, :]
+
+
 def split_gain_ratios(hist: jnp.ndarray) -> jnp.ndarray:
     """Gain ratio of every candidate split. Eq. (2)-(6), vectorized.
 
@@ -105,26 +116,31 @@ def split_gain_ratios(hist: jnp.ndarray) -> jnp.ndarray:
       gr: [..., F, B-1] gain ratio of splitting feature f at threshold b
           (left = bins 0..b). Invalid (empty-side) splits get -inf.
     """
-    cum = jnp.cumsum(hist, axis=-2)
-    return split_gain_ratios_from_cumsum(cum, cum[..., -1, :])
+    return split_gain_ratios_from_cumsum(*left_total(jnp.cumsum(hist, axis=-2)))
 
 
-def variance_gains_from_cumsum(cum: jnp.ndarray, total: jnp.ndarray) -> jnp.ndarray:
+def variance_gains_from_cumsum(
+    left: jnp.ndarray, total: jnp.ndarray, *, axis: int = -1
+) -> jnp.ndarray:
     """Regression analogue of ``split_gain_ratios_from_cumsum``.
 
     Args:
-      cum:   [..., F, B, 3] prefix sums of the [count, sum, sumsq] channels.
-      total: [..., F, 3].
+      left:  ``[..., F, B-1, 3]`` prefix sums of the [count, sum, sumsq]
+             channels, last bin excluded.
+      total: ``[..., F, 1, 3]``.
+      axis:  the channel axis of both.
     Returns: [..., F, B-1] variance reduction (invalid -> -inf).
     """
 
-    def sse(h):
-        return h[..., 2] - h[..., 1] * h[..., 1] / jnp.maximum(h[..., 0], 1e-38)
+    def ch(h, i):
+        return jax.lax.index_in_dim(h, i, axis, keepdims=False)
 
-    left = cum[..., :-1, :]
-    right = total[..., None, :] - left
-    gain = sse(total)[..., None] - sse(left) - sse(right)
-    valid = (left[..., 0] > 0) & (right[..., 0] > 0)
+    def sse(h):
+        return ch(h, 2) - ch(h, 1) * ch(h, 1) / jnp.maximum(ch(h, 0), 1e-38)
+
+    right = total - left
+    gain = sse(total) - sse(left) - sse(right)
+    valid = (ch(left, 0) > 0) & (ch(right, 0) > 0)
     return jnp.where(valid, gain, -jnp.inf)
 
 
@@ -135,8 +151,7 @@ def variance_gains(sum_hist, sumsq_hist, cnt_hist):
     Returns: [..., F, B-1] gain (invalid -> -inf).
     """
     hist = jnp.stack([cnt_hist, sum_hist, sumsq_hist], axis=-1)
-    cum = jnp.cumsum(hist, axis=-2)
-    return variance_gains_from_cumsum(cum, cum[..., -1, :])
+    return variance_gains_from_cumsum(*left_total(jnp.cumsum(hist, axis=-2)))
 
 
 def _select_winners(gr: jnp.ndarray, cum: jnp.ndarray, total: jnp.ndarray) -> SplitScores:
@@ -177,7 +192,7 @@ def best_splits(hist: jnp.ndarray, feature_mask: jnp.ndarray | None = None) -> S
     """
     cum = jnp.cumsum(hist, axis=-2)                 # the ONE bin scan
     total = cum[..., -1, :]
-    gr = split_gain_ratios_from_cumsum(cum, total)  # [k, S, F, B-1]
+    gr = split_gain_ratios_from_cumsum(*left_total(cum))  # [k, S, F, B-1]
     if feature_mask is not None:
         gr = jnp.where(feature_mask[:, None, :, None], gr, -jnp.inf)
     return _select_winners(gr, cum, total)
@@ -271,7 +286,7 @@ def level_scores(
     elif regression:
         cum = jnp.cumsum(hist, axis=-2)
         total = cum[..., -1, :]
-        gains = variance_gains_from_cumsum(cum, total)
+        gains = variance_gains_from_cumsum(*left_total(cum))
         if feature_mask is not None:
             gains = jnp.where(feature_mask[:, None, :, None], gains, -jnp.inf)
         scores = _select_winners(gains, cum, total)

@@ -20,10 +20,10 @@ Both backends apply the per-tree weight *inside* the per-tree step so
 the ``[k, N, C]`` weighted-channel tensor is never materialized —
 ensemble growth costs k*N weights, not k*N*C activations (the DSI
 data-multiplexing property). ``packed=True`` (classification-shaped
-one-hot channels only) additionally folds the class index into the
-scatter/one-hot index, so the inner loop reads the ``[N]`` weight vector
-instead of the ``[N, C]`` channel matrix — a C-fold cut of T_GR's
-dominant memory traffic (§Perf log, PERF.md).
+one-hot channels only) folds the class index into the segment id, so
+``segment_sum`` runs one scatter of the ``[k, N]`` weights per feature
+instead of one per channel. It does not apply to the pallas kernel,
+whose one contraction covers every channel.
 
 The distributed path (core/distributed.py) calls the same function on
 each device's (sample-shard x feature-shard) block and psums over the
@@ -51,6 +51,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..kernels import default_interpret
 from ..kernels.gain_ratio.kernel import multi_tree_hist_pallas
 
 BACKENDS = ("auto", "pallas", "segment_sum")
@@ -65,9 +66,7 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
-def hist_feature_slab(
-    N: int, F: int, S: int, B: int, C: int, *, packed: bool = False
-) -> int:
+def hist_feature_slab(N: int, F: int, S: int, B: int, C: int) -> int:
     """Feature-slab width for blocked histogram consumption.
 
     This is exactly the pallas hist kernel's own ``f_blk`` for the
@@ -79,7 +78,7 @@ def hist_feature_slab(
     """
     from ..kernels.gain_ratio.kernel import choose_blocks
 
-    return choose_blocks(N, F, S, B, C, packed=packed)[1]
+    return choose_blocks(N, F, S, B, C)[1]
 
 
 @partial(
@@ -101,8 +100,9 @@ def level_histograms(
     """hist[t,s,f,b,c] = sum_i w[t,i] * base[i,c] * [slot_i = s] * [x_if = b].
 
     ``base_channels`` is ``onehot(y)`` for classification or
-    ``[1, y, y^2]`` for regression — same kernel either way (``packed``
-    requires the classification-shaped one-hot form).
+    ``[1, y, y^2]`` for regression — same kernel either way. ``packed``
+    (classification-shaped one-hot channels only) changes the
+    ``segment_sum`` scatter layout and is ignored by the pallas kernel.
 
     ``interpret`` only affects the pallas backend; ``None`` means
     interpret off-TPU, compiled on TPU.
@@ -112,48 +112,44 @@ def level_histograms(
     backend = resolve_backend(backend)
     if backend == "pallas":
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = default_interpret()
         return multi_tree_hist_pallas(
             x_binned, base_channels, weights, sample_slot,
-            n_slots=n_slots, n_bins=n_bins, packed=packed,
-            interpret=interpret,
+            n_slots=n_slots, n_bins=n_bins, interpret=interpret,
         )
 
-    N, F = x_binned.shape
+    k = weights.shape[0]
     C = base_channels.shape[-1]
     S, B = n_slots, n_bins
-
+    parked = sample_slot < 0
     if packed:
+        # Class index folded into the segment id: one scatter of the [k, N]
+        # per-sample weights per feature.
         cls = jnp.argmax(base_channels, axis=-1).astype(jnp.int32)   # [N]
         wcls = base_channels.max(axis=-1)                            # per-sample scale
+        seg0 = jnp.where(parked, S, sample_slot) * (B * C) + cls[None, :]
+        updates, width = [weights * wcls[None, :]], B * C
+    else:
+        # One scatter per channel of the [k, N] weighted channel c.
+        seg0 = jnp.where(parked, S, sample_slot) * B       # parked -> dump segment
+        updates, width = [weights * base_channels[:, c][None, :] for c in range(C)], B
+    step = C if packed else 1
+    n_seg = (S + 1) * width
 
-        def per_tree_packed(w, slot):
-            wv = w * wcls
-            base = jnp.where(slot >= 0, slot, S) * (B * C)
+    def scatter(seg, u):                          # [N] ids, [N] values
+        return jax.ops.segment_sum(u, seg, num_segments=n_seg)
 
-            def per_feature(bins_f):
-                seg = base + bins_f.astype(jnp.int32) * C + cls
-                out = jax.ops.segment_sum(wv, seg, num_segments=S * B * C + B * C)
-                return out[: S * B * C].reshape(S, B, C)
+    def per_feature(bins_f):                      # [N] bins of one feature
+        seg = seg0 + bins_f.astype(jnp.int32)[None, :] * step          # [k, N]
+        outs = [jax.vmap(scatter)(seg, u)[:, :S * width] for u in updates]
+        return jnp.stack(outs, axis=-1).reshape(k, S, B, C)
 
-            return jax.vmap(per_feature, in_axes=1)(x_binned)
-
-        hist = jax.vmap(per_tree_packed)(weights, sample_slot)
-        return jnp.transpose(hist, (0, 2, 1, 3, 4))
-
-    def per_tree(w, slot):                        # w [N], slot [N]
-        ch = w[:, None] * base_channels           # fused by XLA
-        base = jnp.where(slot >= 0, slot, S) * B  # parked -> dump segment
-
-        def per_feature(bins_f):                  # [N] uint8
-            seg = base + bins_f
-            out = jax.ops.segment_sum(ch, seg, num_segments=S * B + B)
-            return out[: S * B].reshape(S, B, C)
-
-        return jax.vmap(per_feature, in_axes=1)(x_binned)   # [F, S, B, C]
-
-    hist = jax.vmap(per_tree)(weights, sample_slot)         # [k, F, S, B, C]
-    return jnp.transpose(hist, (0, 2, 1, 3, 4))
+    # Features are scanned, not vmapped: a vmap would broadcast the [k, N]
+    # updates to [k, F, N] before the scatter. Every per-sample operand
+    # keeps the samples minor (no [.., N, C] array, whose class axis
+    # would pad to a full TPU lane tile).
+    hist = jax.lax.map(per_feature, x_binned.T)             # [F, k, S, B, C]
+    return jnp.transpose(hist, (1, 2, 0, 3, 4))
 
 
 def blocked_level_histograms(

@@ -97,9 +97,9 @@ class ForestConfig:
     bin_fit: str = "auto"
     regression: bool = False
     # --- §Perf optimizations (beyond-paper; see EXPERIMENTS.md §Perf) ------
-    packed_hist: bool = False         # class index folded into segment ids
+    packed_hist: bool = False         # segment_sum: class index in segment ids
     hist_reduce: str = "psum"         # psum | psum_scatter (distributed T_GR)
-    # Sibling-subtraction histogram reuse (PERF.md §Histogram reuse):
+    # Sibling-subtraction histogram reuse:
     # between levels the engine carries the previous level's per-slot
     # histograms, histograms ONLY samples routed to the *smaller* child
     # of each split, and reconstructs every large child as

@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .forest import fused_vote_scores, predict_proba_trees, predict_value_trees
+from .forest import (
+    fused_vote_scores, predict_label_trees, predict_proba_trees, predict_value_trees,
+)
 from .types import Forest
 
 PREDICT_BACKENDS = ("auto", "pallas", "xla")
@@ -56,8 +58,7 @@ def oob_accuracy(
     either way and gets the **neutral prior 0.5**, never a degenerate
     0/0.
     """
-    probs = predict_proba_trees(forest, x_binned)          # [k, N, C]
-    pred = jnp.argmax(probs, axis=-1)                      # [k, N]
+    pred = predict_label_trees(forest, x_binned)           # [k, N]
     oob = (weights == 0.0).astype(jnp.float32)             # [k, N]
     correct = jnp.sum(oob * (pred == y[None]).astype(jnp.float32), axis=1)
     total = jnp.sum(oob, axis=1)
@@ -150,8 +151,7 @@ def _block_feeder(x_binned, sample_block, prefetch, *, what,
 @jax.jit
 def _oob_block_counts(forest: Forest, xb_b, y_b, w_b):
     """One block's contribution to Eq. (8): (#correct, #OOB) per tree."""
-    probs = predict_proba_trees(forest, xb_b)              # [k, Nb, C]
-    pred = jnp.argmax(probs, axis=-1)
+    pred = predict_label_trees(forest, xb_b)               # [k, Nb]
     oob = (w_b == 0.0).astype(jnp.float32)
     correct = jnp.sum(oob * (pred == y_b[None]).astype(jnp.float32), axis=1)
     return correct, jnp.sum(oob, axis=1)

@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import default_interpret
 from .kernel import hist_pallas_call
 from .ref import histogram_ref
 
@@ -27,15 +28,17 @@ def fused_histogram(
     n_slots: int,
     n_bins: int,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
     n_blk: int | None = None,
     f_blk: int | None = None,
 ) -> jnp.ndarray:
-    """Single-tree hist [S, F, B, C]; Pallas on TPU, jnp oracle otherwise."""
+    """Single-tree hist [S, F, B, C]: the Pallas kernel, or the jnp
+    oracle with ``use_pallas=False``. ``interpret=None`` emulates the
+    kernel off-TPU and compiles it on TPU."""
     if not use_pallas:
         return histogram_ref(x_bins, wch, slot, n_slots=n_slots, n_bins=n_bins)
     return hist_pallas_call(
         x_bins, wch, slot,
-        n_slots=n_slots, n_bins=n_bins,
-        n_blk=n_blk, f_blk=f_blk, interpret=interpret,
+        n_slots=n_slots, n_bins=n_bins, n_blk=n_blk, f_blk=f_blk,
+        interpret=default_interpret() if interpret is None else interpret,
     )

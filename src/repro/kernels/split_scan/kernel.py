@@ -1,41 +1,40 @@
 """Pallas TPU kernel: fused T_NS split scoring (paper §4.2.1, Eq. 2-6).
 
-PR 1 made T_GR (histogram construction) a Pallas kernel but still wrote
-the full ``[tc, S, F, B, C]`` histogram to HBM for ``core/gain.py`` to
-re-read — for training shapes that tensor is orders of magnitude larger
-than the ``O(k*S)`` split descriptors that survive the level. This
-kernel closes the loop: it consumes histogram tiles ``[1, S, f_blk, B,
-C]`` in VMEM, computes the bin-cumsum, Eq. (2)-(6) gain ratios (or the
-``variance_gains`` regression analogue) and the dim-reduction feature
-mask in-register, and folds the T_NS argmax into the grid loop as a
-running ``(best_gr, best_f, best_thr, left/right_counts)`` accumulator.
-Only the per-(tree, slot) winners are ever written back.
+The histogram kernel (``kernels/gain_ratio``) writes the ``[tc, S, F,
+B, C]`` level histogram; for training shapes that tensor is orders of
+magnitude larger than the ``O(k*S)`` split descriptors that survive the
+level. This kernel consumes histogram tiles in VMEM, computes the bin
+prefix sums, the Eq. (2)-(6) gain ratios (or the ``variance_gains``
+regression analogue) and the dim-reduction feature mask in-register,
+and folds the T_NS argmax into the grid loop as a running ``(best_gr,
+best_f, best_thr, left/right_counts)`` accumulator. Only the per-(tree,
+slot) winners are ever written back.
+
+Layout: inside the kernel a feature's histogram is ``[C, B, S]`` — the
+frontier slots on the lane axis, the bins on sublanes, the classes
+leading — so no block has a class-sized lane dimension. The bin prefix
+sum is one ``[B, B]`` lower-triangular 0/1 matmul per class at
+``Precision.HIGHEST``: exact for integer counts below 2**24, which is
+what DSI-weighted classification histograms hold.
 
 Grid: ``(tc, F_blocks)`` with the feature axis innermost (sequential),
 so each tree's [S]-shaped accumulator stays resident in VMEM while
-feature blocks stream through — the same reduction-grid pattern as
-``kernels/gain_ratio``. The carry is *resumable*: callers pass the
-previous best as inputs (seeded into the output at the first feature
-block) plus a global feature offset, which is how
-``core/forest.fused_level_scores`` chains histogram-kernel -> score-
-kernel per feature slab without ever materializing the full histogram,
-and how ``core/distributed.py`` scores each shard's feature slice
-post-combine.
+feature blocks stream through; within a block the features are folded
+one at a time. The carry is *resumable*: callers pass the previous best
+as inputs (seeded into the output at the first feature block) plus a
+global feature offset, which is how ``core/engine.fused_level_scores``
+chains histogram-kernel -> score-kernel per feature slab without ever
+materializing the full histogram, and how ``core/distributed.py``
+scores each shard's feature slice post-combine.
 
 Numerics are shared with the XLA backend (``core/gain.py``'s
-``*_from_cumsum`` scorers) and carry updates are strictly-greater, so
-first-occurrence argmax semantics match exactly. Gain *values* agree to
-float rounding only (XLA fuses the two compiled contexts differently —
-FMA/reassociation). Winners and child counts are bit-identical wherever
-the backends share XLA numerics — interpret mode (the tested path) and
-real training data, where integer DSI weights make every histogram and
-its prefix sums exact — so ``grow_forest`` builds bit-identical forests
-whichever backend scores the splits (tests/test_split_backends.py).
-Caveat: on a real TPU (``interpret=False``) Mosaic may round the
-log/division chain differently from XLA, so two *near-tied* candidate
-splits could in principle flip order vs the xla backend; the forests
-remain valid, but exact cross-backend identity is only asserted where
-it can be tested.
+``*_from_cumsum`` scorers, evaluated with the class axis leading) and
+carry updates are strictly-greater, so first-occurrence argmax
+semantics match exactly: winners and child counts are bit-identical in
+interpret mode (tests/test_split_backends.py). On a TPU, Mosaic's
+log/division may round differently from XLA's, so two *near-tied*
+candidate splits could flip order against the xla backend; the forests
+stay valid either way.
 """
 from __future__ import annotations
 
@@ -48,23 +47,31 @@ from jax.experimental import pallas as pl
 from ...core.gain import (
     SplitScores, split_gain_ratios_from_cumsum, variance_gains_from_cumsum,
 )
-from ..gain_ratio.kernel import _VMEM_BUDGET, _round_up
+from .. import default_interpret
+from ..gain_ratio.kernel import _HIGHEST, _VMEM_BUDGET, _round_up, _tile_words
+
+
+def score_vmem_bytes(f_blk: int, S: int, B: int, C: int) -> int:
+    """Estimated VMEM of one split-scan grid step: the double-buffered
+    ``[f_blk, C, B, S]`` histogram block, mask block and carry blocks
+    (in and out), plus one feature's temporaries (prefix sums, left and
+    right counts, entropy terms and the HIGHEST operand splits)."""
+    carry = (3 + 2 * C) * _tile_words(1, S)
+    blocks = f_blk * C * _tile_words(B, S) + f_blk * _tile_words(1, 1) + 2 * carry
+    temps = 12 * C * _tile_words(B, S) + 3 * _tile_words(B, B)
+    return 4 * (2 * blocks + temps)
 
 
 def choose_score_block(
     S: int, B: int, C: int, F: int, *,
     f_blk: int | None = None, vmem_budget: int = _VMEM_BUDGET,
 ) -> int:
-    """Feature-block width for the score kernel, from the same VMEM
-    budget as ``gain_ratio.choose_blocks``.
-
-    Working set per grid step is ~6 slab-sized f32 arrays (hist tile,
-    bin cumsum, right counts, scores, winner one-hot, scratch):
-    ``6 * f_blk * S*B * C * 4`` bytes must fit the budget.
-    """
+    """Feature-block width for the score kernel: a multiple of 8, halved
+    from 32 while ``score_vmem_bytes`` exceeds the shared budget, never
+    padding past one block of the real problem."""
     if f_blk is None:
-        f_blk = 128
-        while f_blk > 8 and 6 * f_blk * S * B * C * 4 > vmem_budget:
+        f_blk = 32
+        while f_blk > 8 and score_vmem_bytes(f_blk, S, B, C) > vmem_budget:
             f_blk //= 2
     return min(f_blk, _round_up(max(F, 1), 8))
 
@@ -72,7 +79,7 @@ def choose_score_block(
 def init_carry(tc: int, S: int, C: int) -> tuple:
     """Neutral running-best carry: no winner yet (feature = -1).
 
-    The kernel force-accepts the first block's argmax while
+    The kernel force-accepts the first feature's argmax while
     ``feature < 0``, which reproduces the XLA oracle's all-invalid
     semantics (gain -inf -> feature 0, threshold 0, counts of that
     split) without a special case.
@@ -92,7 +99,8 @@ def _split_scan_kernel(
     gr_ref, f_ref, thr_ref, l_ref, r_ref,
     *, f_blk: int, regression: bool,
 ):
-    """One (tree, feature-block) grid step: score the slab, fold argmax."""
+    """One (tree, feature-block) grid step: score each feature of the
+    block and fold its argmax into the running best."""
     fj = pl.program_id(1)
 
     @pl.when(fj == 0)
@@ -103,45 +111,47 @@ def _split_scan_kernel(
         l_ref[...] = l0_ref[...]
         r_ref[...] = r0_ref[...]
 
-    hist = hist_ref[0]                                # [S, f_blk, B, C]
-    S, Fb, B, C = hist.shape
-    cum = jnp.cumsum(hist, axis=-2)                   # the ONE bin scan
-    total = cum[:, :, -1, :]                          # [S, f_blk, C]
-    if regression:
-        sc = variance_gains_from_cumsum(cum, total)   # [S, f_blk, B-1]
-    else:
-        sc = split_gain_ratios_from_cumsum(cum, total)
-    admit = mask_ref[0, :] > 0                        # [f_blk]
-    sc = jnp.where(admit[None, :, None], sc, -jnp.inf)
+    _, C, B, S = hist_ref.shape
+    # tri[b, b'] = [b' <= b]: tri @ h is the prefix sum over bins.
+    tri = (
+        jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
+        <= jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
+    ).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (B - 1, S), 0)
+    f_first = fbase_ref[...] + fj * f_blk               # [1, 1] global id
+    scorer = variance_gains_from_cumsum if regression else split_gain_ratios_from_cumsum
 
-    # Block argmax with first-occurrence tie-break (== jnp.argmax).
-    flat = sc.reshape(S, Fb * (B - 1))
-    m = jnp.max(flat, axis=-1)                        # [S]
-    col = jax.lax.broadcasted_iota(jnp.int32, flat.shape, 1)
-    idx = jnp.min(jnp.where(flat == m[:, None], col, Fb * (B - 1)), axis=-1)
+    def body(f, _):
+        h = hist_ref[f]                                 # [C, B, S]
+        cum = jnp.stack([
+            jnp.dot(tri, h[c], precision=_HIGHEST,
+                    preferred_element_type=jnp.float32)
+            for c in range(C)
+        ])                                              # [C, B, S]
+        left, total = cum[:, :B - 1, :], cum[:, B - 1:, :]
+        sc = scorer(left, total, axis=0)                # [B-1, S]
+        sc = jnp.where(mask_ref[f] > 0, sc, -jnp.inf)
 
-    # Winner child counts, gathered from the cumsum via a one-hot
-    # multiply-reduce (TPUs have no fast gather; exact — all other
-    # summands are literal zeros).
-    one = (col == idx[:, None]).astype(hist.dtype).reshape(S, Fb, B - 1)
-    left = cum[:, :, :-1, :]                          # [S, f_blk, B-1, C]
-    lcnt = jnp.sum(one[..., None] * left, axis=(1, 2))                      # [S, C]
-    rcnt = jnp.sum(one[..., None] * (total[:, :, None, :] - left), axis=(1, 2))
+        # Feature argmax with first-occurrence tie-break (== jnp.argmax).
+        m = jnp.max(sc, axis=0, keepdims=True)          # [1, S]
+        thr = jnp.min(jnp.where(sc == m, row, B - 1), axis=0, keepdims=True)
+        # Winner child counts via a one-hot multiply-reduce (TPUs have
+        # no fast gather; exact — all other summands are literal zeros).
+        one = (row == thr).astype(jnp.float32)
+        lcnt = jnp.sum(one[None] * left, axis=1, keepdims=True)   # [C, 1, S]
 
-    fl = (idx // (B - 1)).astype(jnp.int32)
-    thr = (idx % (B - 1)).astype(jnp.int32)
-    f_glob = fbase_ref[0] + fj * f_blk + fl           # [S] global feature id
+        # Strictly-greater keeps the earliest (lowest feature-id) winner
+        # on ties; `feature < 0` force-accepts the very first feature so
+        # the neutral carry never survives.
+        better = (m > gr_ref[...]) | (f_ref[...] < 0)   # [1, S]
+        gr_ref[...] = jnp.where(better, m, gr_ref[...])
+        thr_ref[...] = jnp.where(better, thr, thr_ref[...])
+        f_ref[...] = jnp.where(better, f_first + f, f_ref[...])
+        l_ref[...] = jnp.where(better, lcnt, l_ref[...])
+        r_ref[...] = jnp.where(better, total - lcnt, r_ref[...])
+        return 0
 
-    # Strictly-greater keeps the earliest (lowest feature-id) winner on
-    # ties; `feature < 0` force-accepts the very first block so the
-    # neutral carry never survives.
-    cur_gr = gr_ref[0]
-    better = (m > cur_gr) | (f_ref[0] < 0)
-    gr_ref[0] = jnp.where(better, m, cur_gr)
-    thr_ref[0] = jnp.where(better, thr, thr_ref[0])
-    l_ref[0] = jnp.where(better[:, None], lcnt, l_ref[0])
-    r_ref[0] = jnp.where(better[:, None], rcnt, r_ref[0])
-    f_ref[0] = jnp.where(better, f_glob, f_ref[0])
+    jax.lax.fori_loop(0, f_blk, body, 0)
 
 
 def split_scan_block(
@@ -163,49 +173,48 @@ def split_scan_block(
     tc, S, F, B, C = hist.shape
     f_blk = choose_score_block(S, B, C, F, f_blk=f_blk)
     Fp = _round_up(F, f_blk)
-    if Fp != F:
-        # Padded features are masked out; they can never win (the
-        # force-accept lands on flat position 0, a real feature).
-        hist = jnp.pad(hist, ((0, 0), (0, 0), (0, Fp - F), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, 0), (0, Fp - F)))
     if carry is None:
         carry = init_carry(tc, S, C)
+    # Kernel layout: [tc, F, C, B, S]; padded features are masked out
+    # and can never win (the force-accept lands on feature 0, a real one).
+    h = jnp.transpose(hist.astype(jnp.float32), (0, 2, 4, 3, 1))
+    h = jnp.pad(h, ((0, 0), (0, Fp - F), (0, 0), (0, 0), (0, 0)))
+    mask4 = jnp.pad(mask.astype(jnp.int32), ((0, 0), (0, Fp - F)))[:, :, None, None]
+    gr0, f0, thr0, l0, r0 = carry
+    row = lambda a: a[:, None, :]                             # [tc, 1, S]
+    cls = lambda a: jnp.transpose(a, (0, 2, 1))[:, :, None, :]  # [tc, C, 1, S]
 
-    grid = (tc, Fp // f_blk)
-    carry_specs = [
-        pl.BlockSpec((1, S), lambda t, f: (t, 0)),
-        pl.BlockSpec((1, S), lambda t, f: (t, 0)),
-        pl.BlockSpec((1, S), lambda t, f: (t, 0)),
-        pl.BlockSpec((1, S, C), lambda t, f: (t, 0, 0)),
-        pl.BlockSpec((1, S, C), lambda t, f: (t, 0, 0)),
-    ]
-    outs = pl.pallas_call(
+    row_spec = pl.BlockSpec((None, 1, S), lambda t, f: (t, 0, 0))
+    cls_spec = pl.BlockSpec((None, C, 1, S), lambda t, f: (t, 0, 0, 0))
+    carry_specs = [row_spec, row_spec, row_spec, cls_spec, cls_spec]
+    gr, fe, thr, l, r = pl.pallas_call(
         functools.partial(
             _split_scan_kernel, f_blk=f_blk, regression=regression
         ),
-        grid=grid,
+        grid=(tc, Fp // f_blk),
         in_specs=[
-            pl.BlockSpec((1, S, f_blk, B, C), lambda t, f: (t, 0, f, 0, 0)),
-            pl.BlockSpec((1, f_blk), lambda t, f: (t, f)),
-            pl.BlockSpec((1,), lambda t, f: (0,)),
+            pl.BlockSpec((None, f_blk, C, B, S), lambda t, f: (t, f, 0, 0, 0)),
+            pl.BlockSpec((None, f_blk, 1, 1), lambda t, f: (t, f, 0, 0)),
+            pl.BlockSpec((1, 1), lambda t, f: (0, 0)),
             *carry_specs,
         ],
         out_specs=carry_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((tc, S), jnp.float32),
-            jax.ShapeDtypeStruct((tc, S), jnp.int32),
-            jax.ShapeDtypeStruct((tc, S), jnp.int32),
-            jax.ShapeDtypeStruct((tc, S, C), jnp.float32),
-            jax.ShapeDtypeStruct((tc, S, C), jnp.float32),
+            jax.ShapeDtypeStruct((tc, 1, S), jnp.float32),
+            jax.ShapeDtypeStruct((tc, 1, S), jnp.int32),
+            jax.ShapeDtypeStruct((tc, 1, S), jnp.int32),
+            jax.ShapeDtypeStruct((tc, C, 1, S), jnp.float32),
+            jax.ShapeDtypeStruct((tc, C, 1, S), jnp.float32),
         ],
         interpret=interpret,
     )(
-        hist.astype(jnp.float32),
-        mask.astype(jnp.int32),
-        jnp.full((1,), f_base, jnp.int32),
-        *carry,
+        h, mask4, jnp.full((1, 1), f_base, jnp.int32),
+        row(gr0.astype(jnp.float32)), row(f0.astype(jnp.int32)),
+        row(thr0.astype(jnp.int32)),
+        cls(l0.astype(jnp.float32)), cls(r0.astype(jnp.float32)),
     )
-    return tuple(outs)
+    uncls = lambda a: jnp.transpose(a[:, :, 0, :], (0, 2, 1))  # [tc, S, C]
+    return gr[:, 0], fe[:, 0], thr[:, 0], uncls(l), uncls(r)
 
 
 def split_scan_scores(
@@ -225,7 +234,7 @@ def split_scan_scores(
     fused_level_scores``, which chains ``split_scan_block`` per slab.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     tc, S, F, B, C = hist.shape
     if mask is None:
         mask = jnp.ones((tc, F), jnp.bool_)

@@ -26,7 +26,7 @@ def fused_split_scores(
     *,
     regression: bool = False,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
     f_blk: int | None = None,
 ) -> SplitScores:
     """SplitScores from a [tc, S, F, B, C] histogram; Pallas or jnp oracle."""

@@ -12,7 +12,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ...core.gain import (
-    _select_winners, split_gain_ratios_from_cumsum, variance_gains_from_cumsum,
+    _select_winners, left_total, split_gain_ratios_from_cumsum,
+    variance_gains_from_cumsum,
 )
 
 
@@ -32,9 +33,9 @@ def split_scan_ref(
     cum = jnp.cumsum(hist, axis=-2)
     total = cum[..., -1, :]
     if regression:
-        sc = variance_gains_from_cumsum(cum, total)
+        sc = variance_gains_from_cumsum(*left_total(cum))
     else:
-        sc = split_gain_ratios_from_cumsum(cum, total)
+        sc = split_gain_ratios_from_cumsum(*left_total(cum))
     if mask is not None:
         sc = jnp.where(mask[:, None, :, None], sc, -jnp.inf)
 

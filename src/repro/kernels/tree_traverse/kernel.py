@@ -27,12 +27,15 @@ normalization (``/ sum_i w_i`` or ``/ k``) happens on the tiny [N]
 result outside. Payload construction lives in ``core/voting.py``.
 
 Grid: ``(N_blocks, k)`` with the tree axis innermost (sequential), so
-each sample block's ``[n_blk, C]`` score tile stays resident in VMEM
-while trees stream through — the same reduction-grid pattern as the
-histogram kernel. TPUs have no fast gather, so the per-depth node
-lookups are one-hot select-reduces over the node pool and the final
-leaf-payload read is a one-hot matmul on the MXU (exact: all other
-summands are literal zeros). The carry is resumable: callers seed the
+each sample block's score tile stays resident in VMEM while trees
+stream through — the same reduction-grid pattern as the histogram
+kernel. TPUs have no fast gather, so the per-depth node lookups are
+one-hot select-reduces over the node pool and the final leaf-payload
+read is a one-hot matmul on the MXU at ``Precision.HIGHEST`` (exact:
+all other summands are literal zeros). The per-tree node rows are fed
+as ``[1, P]`` blocks and the payload and scores with the class axis
+leading (``[C, P]``, ``[C, N]``), so no block has a unit or a
+class-sized lane dimension. The carry is resumable: callers seed the
 score tile from a previous call's output (``core/forest.
 fused_vote_scores`` chains tree chunks; ``serving/prf_service.py``
 feeds each shard's partial votes into one ``psum``).
@@ -45,33 +48,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..gain_ratio.kernel import _VMEM_BUDGET, _round_up
+from ..gain_ratio.kernel import _HIGHEST, _VMEM_BUDGET, _round_up, _tile_words
 
 
-def default_interpret() -> bool:
-    """Interpret-mode emulation off-TPU, compiled on TPU — the ONE
-    resolution rule every traversal caller shares (ops.fused_vote,
-    core/forest.fused_vote_scores)."""
-    return jax.default_backend() != "tpu"
+def traverse_vmem_bytes(n_blk: int, P: int, F: int, C: int) -> int:
+    """Estimated VMEM of one traversal grid step: the double-buffered
+    bins ``[n_blk, F]``, node rows ``3 x [1, P]``, payload ``[C, P]``,
+    carry and score ``[C, n_blk]`` blocks, plus what the compiler's
+    reported sizes show it keeps of the depth walk: eight per-sample
+    ``[n_blk, 1]`` columns (node, feature, threshold, child, bin, ...;
+    one 128-lane tile row per sample each) and one 128-row strip of the
+    ``[n_blk, P]`` node one-hot. The one-hot is never held whole."""
+    Pp = _round_up(P, 8)
+    blocks = (
+        _tile_words(n_blk, F) + 3 * _tile_words(1, Pp) + _tile_words(C, Pp)
+        + 2 * _tile_words(C, n_blk)
+    )
+    temps = 8 * _tile_words(n_blk, 1) + _tile_words(128, Pp)
+    return 4 * (2 * blocks + temps)
 
 
 def choose_traverse_block(
     P: int, F: int, C: int, *,
     n_blk: int | None = None, vmem_budget: int = _VMEM_BUDGET,
 ) -> int:
-    """Sample-block height for the traversal kernel, from the shared
-    VMEM budget.
-
-    Working set per grid step is dominated by the [n_blk, P] one-hot
-    node selector and its ~4 gather temporaries, plus the [n_blk, F]
-    bins tile and feature one-hot and the [n_blk, C] score tile:
-    ``n_blk * (6P + 2F + 2C) * 4`` bytes must fit the budget.
-    """
+    """Sample-block width for the traversal kernel: a multiple of 128
+    (the lane axis of the score block), halved from 512 while
+    ``traverse_vmem_bytes`` exceeds the shared budget."""
     if n_blk is None:
         n_blk = 512
-        while n_blk > 8 and n_blk * (6 * P + 2 * F + 2 * C) * 4 > vmem_budget:
+        while n_blk > 128 and traverse_vmem_bytes(n_blk, P, F, C) > vmem_budget:
             n_blk //= 2
-    return n_blk
+    return _round_up(n_blk, 128)
 
 
 def _traverse_kernel(
@@ -113,12 +121,12 @@ def _traverse_kernel(
 
     # Leaf payload read as a one-hot matmul on the MXU (exact).
     onehot = (pcol == node[:, None]).astype(jnp.float32)
-    votes = jax.lax.dot_general(
-        onehot, payload_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),     # onehot @ payload
+    out_ref[...] += jax.lax.dot_general(
+        payload_ref[...], onehot,
+        dimension_numbers=(((1,), (1,)), ((), ())),     # payload @ onehot^T
+        precision=_HIGHEST,
         preferred_element_type=jnp.float32,
-    )                                                   # [n_blk, C]
-    out_ref[...] += votes
+    )                                                   # [C, n_blk]
 
 
 def traverse_block(
@@ -144,47 +152,43 @@ def traverse_block(
     tc, P = feature.shape
     C = payload.shape[-1]
     n_blk = choose_traverse_block(P, F, C, n_blk=n_blk)
-    n_blk = min(n_blk, _round_up(max(N, 1), 8))
+    n_blk = min(n_blk, _round_up(max(N, 1), 128))
 
     Np, Fp, Pp = _round_up(N, n_blk), _round_up(F, 8), _round_up(P, 8)
-    xb = x_binned.astype(jnp.int32)
-    if Np != N or Fp != F:
-        # Padded samples traverse the tree like real ones but are
-        # sliced off the output; padded feature columns are never
-        # addressed (real feature ids < F).
-        xb = jnp.pad(xb, ((0, Np - N), (0, Fp - F)))
-    if Pp != P:
-        # Padded pool slots are leaves with zero payload; unreachable
-        # anyway (traversal starts at the root, slot 0).
-        feature = jnp.pad(feature, ((0, 0), (0, Pp - P)), constant_values=-1)
-        threshold = jnp.pad(threshold, ((0, 0), (0, Pp - P)))
-        left_child = jnp.pad(left_child, ((0, 0), (0, Pp - P)))
-        payload = jnp.pad(payload, ((0, 0), (0, Pp - P), (0, 0)))
+    # Padded samples traverse the tree like real ones but are sliced off
+    # the output; padded feature columns are never addressed (real
+    # feature ids < F). Padded pool slots are leaves with zero payload,
+    # unreachable anyway (traversal starts at the root, slot 0).
+    xb = jnp.pad(x_binned.astype(jnp.int32), ((0, Np - N), (0, Fp - F)))
+    node_row = lambda a, fill: jnp.pad(
+        a.astype(jnp.int32), ((0, 0), (0, Pp - P)), constant_values=fill
+    )[:, None, :]                                                # [tc, 1, Pp]
+    payload_t = jnp.transpose(
+        jnp.pad(payload.astype(jnp.float32), ((0, 0), (0, Pp - P), (0, 0))),
+        (0, 2, 1),
+    )                                                            # [tc, C, Pp]
     if carry is None:
         carry = jnp.zeros((N, C), jnp.float32)
-    carry = jnp.pad(carry.astype(jnp.float32), ((0, Np - N), (0, 0)))
+    carry_t = jnp.pad(carry.astype(jnp.float32), ((0, Np - N), (0, 0))).T
 
-    grid = (Np // n_blk, tc)
+    row_spec = pl.BlockSpec((None, 1, Pp), lambda n, t: (t, 0, 0))
+    score_spec = pl.BlockSpec((C, n_blk), lambda n, t: (0, n))
     out = pl.pallas_call(
         functools.partial(_traverse_kernel, depth=depth),
-        grid=grid,
+        grid=(Np // n_blk, tc),
         in_specs=[
             pl.BlockSpec((n_blk, Fp), lambda n, t: (n, 0)),      # bins
-            pl.BlockSpec((1, Pp), lambda n, t: (t, 0)),          # feature
-            pl.BlockSpec((1, Pp), lambda n, t: (t, 0)),          # threshold
-            pl.BlockSpec((1, Pp), lambda n, t: (t, 0)),          # left_child
-            pl.BlockSpec((1, Pp, C), lambda n, t: (t, 0, 0)),    # payload
-            pl.BlockSpec((n_blk, C), lambda n, t: (n, 0)),       # carry
+            row_spec,                                            # feature
+            row_spec,                                            # threshold
+            row_spec,                                            # left_child
+            pl.BlockSpec((None, C, Pp), lambda n, t: (t, 0, 0)), # payload^T
+            score_spec,                                          # carry^T
         ],
-        out_specs=pl.BlockSpec((n_blk, C), lambda n, t: (n, 0)),
-        out_shape=jax.ShapeDtypeStruct((Np, C), jnp.float32),
+        out_specs=score_spec,
+        out_shape=jax.ShapeDtypeStruct((C, Np), jnp.float32),
         interpret=interpret,
     )(
-        xb,
-        feature.astype(jnp.int32),
-        threshold.astype(jnp.int32),
-        left_child.astype(jnp.int32),
-        payload.astype(jnp.float32),
-        carry,
+        xb, node_row(feature, -1), node_row(threshold, 0),
+        node_row(left_child, 0), payload_t, carry_t,
     )
-    return out[:N]
+    return out[:, :N].T

@@ -5,6 +5,7 @@ from functools import partial
 
 import jax
 
+from .. import default_interpret
 from .kernel import traverse_block
 from .ref import traverse_ref
 
@@ -34,7 +35,7 @@ def fused_vote(
 ):
     """Weighted-vote scores [N, C] from a node-pool forest; Pallas or oracle.
 
-    ``interpret=None`` resolves via ``kernel.default_interpret`` (the
+    ``interpret=None`` resolves via ``kernels.default_interpret`` (the
     shared rule: emulation off-TPU, compiled on TPU), so backend
     selection cannot diverge across callers — the serving layer's
     sharded path routes through here.
@@ -45,8 +46,6 @@ def fused_vote(
             depth=depth,
         )
     if interpret is None:
-        from .kernel import default_interpret
-
         interpret = default_interpret()
     return traverse_block(
         x_binned, feature, threshold, left_child, payload, carry,

@@ -71,10 +71,7 @@ def initialize(
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{local_device_count}"
             ).strip()
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # non-CPU backend, or a jax without the knob
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -292,14 +289,13 @@ class MultiHostMesh:
         key = ("psum_hosts", n)
         fn = self._jit_cache.get(key)
         if fn is None:
-            from ..core.distributed import _shard_map
-
             def kernel(x_loc):
                 return jax.lax.psum(x_loc[0], self.sample_axes)
 
-            fn = jax.jit(_shard_map(
+            fn = jax.jit(jax.shard_map(
                 kernel, mesh=self.mesh,
                 in_specs=(P(self.sample_axes),), out_specs=P(),
+                check_vma=False,
             ))
             self._jit_cache[key] = fn
         out = np.asarray(jax.device_get(fn(g))).astype(np.int64)  # [n, 3]

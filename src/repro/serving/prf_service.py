@@ -75,7 +75,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.api import PRFModel
 from ..core.binning import apply_bins
-from ..core.distributed import _shard_map
 from ..core.types import Forest
 from ..core.voting import (
     _vote_weights, build_payload, predict_regression, predict_scores,
@@ -892,11 +891,12 @@ def make_sharded_vote_fn(forest: Forest, mesh, *, tree_axis: str = "data"):
         return jnp.argmax(scores, axis=-1)
 
     fn = jax.jit(
-        _shard_map(
+        jax.shard_map(
             shard,
             mesh=mesh,
             in_specs=(P(tree_axis), P(tree_axis), P(tree_axis), P(tree_axis), P()),
             out_specs=P(),
+            check_vma=False,
         )
     )
 

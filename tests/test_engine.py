@@ -347,7 +347,7 @@ def test_mesh_plane_matches_local_bitwise():
         from repro.core import ForestConfig
         from repro.core.binning import bin_dataset
         from repro.core.distributed import (
-            _grow_sharded, _shard_map, grow_forest_streamed_sharded,
+            _grow_sharded, grow_forest_streamed_sharded,
             oob_accuracy_streamed_sharded, predict_streamed_sharded,
         )
         from repro.core.dsi import bootstrap_counts
@@ -381,10 +381,11 @@ def test_mesh_plane_matches_local_bitwise():
                     return _grow_sharded(xb_loc, base_loc, w_loc, None, _cfg,
                                          sample_axes=("data",),
                                          feature_axis="model")
-                f_mesh = jax.jit(_shard_map(
+                f_mesh = jax.jit(jax.shard_map(
                     kernel, mesh=mesh,
                     in_specs=(P("data", "model"), P("data"), P(None, "data")),
                     out_specs=P(),
+                    check_vma=False,
                 ))(xb_dev, y_dev, w)
                 f_loc = grow_forest(xb_dev, y_dev, w, cfg)
                 for n in ARRS:

@@ -50,9 +50,10 @@ SHAPES = [
 @pytest.mark.parametrize("tc,n,f,s,b,c", SHAPES)
 @pytest.mark.parametrize("packed", [False, True])
 def test_pallas_matches_oracles_classification(tc, n, f, s, b, c, packed):
+    """The kernel against both ``segment_sum`` layouts and the oracle."""
     xb, base, w, slot = _random_case(tc, n, f, s, b, c, "classification")
     got = multi_tree_hist_pallas(
-        xb, base, w, slot, n_slots=s, n_bins=b, packed=packed, interpret=True
+        xb, base, w, slot, n_slots=s, n_bins=b, interpret=True
     )
     want_seg = level_histograms(
         xb, base, w, slot, n_slots=s, n_bins=b, packed=packed,
@@ -70,7 +71,7 @@ def test_pallas_matches_oracles_regression(tc, n, f, s, b, c):
     """Regression channels [1, y, y^2] — unpacked layout only."""
     xb, base, w, slot = _random_case(tc, n, f, s, b, 3, "regression")
     got = multi_tree_hist_pallas(
-        xb, base, w, slot, n_slots=s, n_bins=b, packed=False, interpret=True
+        xb, base, w, slot, n_slots=s, n_bins=b, interpret=True
     )
     want = level_histograms(
         xb, base, w, slot, n_slots=s, n_bins=b, backend="segment_sum"
@@ -109,17 +110,20 @@ def test_resolve_backend():
 
 
 def test_choose_blocks_fits_budget():
-    for (n, f, s, b, c, packed) in [
-        (10_000, 500, 64, 64, 8, False),
-        (10_000, 500, 64, 64, 8, True),
-        (100, 3, 1, 4, 2, False),
+    from repro.kernels.gain_ratio.kernel import _VMEM_BUDGET, hist_vmem_bytes
+
+    for (n, f, s, b, c) in [
+        (10_000, 500, 64, 64, 8),
+        (1_000_000, 28, 256, 64, 2),
+        (1_000_000, 28, 128, 64, 2),
+        (100, 3, 1, 4, 2),
     ]:
-        n_blk, f_blk = choose_blocks(n, f, s, b, c, packed=packed)
-        width = s * b * c if packed else s * b
-        out_bytes = f_blk * s * b * c * 4
-        in_bytes = n_blk * (width + f_blk + c + 2) * 4
-        assert out_bytes + in_bytes <= 16 * 2 ** 20, (n_blk, f_blk)
-        assert n_blk >= 8 and f_blk >= 8
+        n_blk, f_blk = choose_blocks(n, f, s, b, c)
+        assert hist_vmem_bytes(n_blk, f_blk, s, b, c) <= _VMEM_BUDGET, (n_blk, f_blk)
+        # TPU tiling: samples are the lane axis, features the sublane axis.
+        assert n_blk % 128 == 0 and f_blk % 8 == 0
+        # Never pads past one block of the real problem.
+        assert n_blk <= -(-n // 128) * 128 and f_blk <= -(-f // 8) * 8
 
 
 @pytest.mark.parametrize("packed", [False, True])

@@ -197,11 +197,10 @@ def test_regression_reuse_within_tolerance():
 
 
 def test_root_hist_bitwise_across_slot_geometries(reuse_case):
-    """The audit behind PERF.md's "shared values, separate passes"
-    verdict: the dimred root-gain histogram (n_slots=1) and growth's
-    level-0 histogram row 0 (n_slots=S off-path, n_slots=R packed
-    reuse path) are the same segment_sum over the same sample order —
-    bitwise equal, all three geometries."""
+    """Shared values, separate passes: the dimred root-gain histogram
+    (n_slots=1) and growth's level-0 histogram row 0 (n_slots=S
+    off-path, n_slots=R packed reuse path) are the same segment_sum
+    over the same sample order — bitwise equal, all three geometries."""
     xb, y, w, cfg = reuse_case
     cfg = cfg.resolved(xb.shape[1])
     base = class_channels(jnp.asarray(y), cfg.n_classes)
@@ -303,7 +302,7 @@ def test_mesh_reuse_parity_and_resume():
         from repro.core import ForestConfig
         from repro.core.binning import bin_dataset
         from repro.core.distributed import (
-            _grow_sharded, _shard_map, grow_forest_streamed_sharded,
+            _grow_sharded, grow_forest_streamed_sharded,
         )
         from repro.core.dsi import bootstrap_counts
         from repro.core.forest import grow_forest
@@ -340,10 +339,11 @@ def test_mesh_reuse_parity_and_resume():
                 return _grow_sharded(xb_loc, base_loc, w_loc, None, _cfg,
                                      sample_axes=("data",),
                                      feature_axis="model")
-            f_mesh = jax.jit(_shard_map(
+            f_mesh = jax.jit(jax.shard_map(
                 kernel, mesh=mesh,
                 in_specs=(P("data", "model"), P("data"), P(None, "data")),
                 out_specs=P(),
+                check_vma=False,
             ))(xb_dev, y_dev, w)
             check(f_mesh, f"resident {hist_reduce}")
             cfg_st = dataclasses.replace(cfg, sample_block=170)

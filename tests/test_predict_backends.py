@@ -72,12 +72,13 @@ def test_kernel_matches_ref(k, p, f, c, n):
 
 
 def test_kernel_forced_small_sample_blocks():
-    """n_blk forced below N: the score tile must survive the N grid axis."""
+    """n_blk forced below N: the score tile must survive the N grid axis
+    (three 128-sample blocks, the last one padded)."""
     depth = 4
     feat, thr, left, payload = _random_pool(3, 17, 6, 2, depth=depth)
-    xb = jnp.asarray(RNG.integers(0, 8, (100, 6)).astype(np.uint8))
+    xb = jnp.asarray(RNG.integers(0, 8, (300, 6)).astype(np.uint8))
     got = traverse_block(
-        xb, feat, thr, left, payload, None, depth=depth, n_blk=16, interpret=True
+        xb, feat, thr, left, payload, None, depth=depth, n_blk=128, interpret=True
     )
     want = traverse_ref(xb, feat, thr, left, payload, depth=depth)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
@@ -125,14 +126,16 @@ def test_ops_wrapper_matches_oracle():
 
 def test_choose_traverse_block_fits_budget():
     from repro.kernels.gain_ratio.kernel import _VMEM_BUDGET
+    from repro.kernels.tree_traverse.kernel import traverse_vmem_bytes
 
-    for (p, f, c) in [(4097, 512, 8), (26, 16, 4), (1025, 64, 2)]:
+    for (p, f, c) in [(4097, 512, 8), (26, 16, 4), (1025, 64, 2), (2049, 28, 2)]:
         n_blk = choose_traverse_block(p, f, c)
-        if n_blk > 8:   # above the halving floor the budget MUST hold
-            assert n_blk * (6 * p + 2 * f + 2 * c) * 4 <= _VMEM_BUDGET
+        assert n_blk % 128 == 0             # the lane axis of the score block
+        if n_blk > 128:   # above the halving floor the budget MUST hold
+            assert traverse_vmem_bytes(n_blk, p, f, c) <= _VMEM_BUDGET
             assert (
                 n_blk == 512
-                or 2 * n_blk * (6 * p + 2 * f + 2 * c) * 4 > _VMEM_BUDGET
+                or traverse_vmem_bytes(2 * n_blk, p, f, c) > _VMEM_BUDGET
             )
 
 

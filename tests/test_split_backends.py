@@ -168,22 +168,24 @@ def test_resolve_split_backend():
 
 def test_choose_score_block_fits_budget():
     from repro.kernels.gain_ratio.kernel import _VMEM_BUDGET
+    from repro.kernels.split_scan.kernel import score_vmem_bytes
 
-    for (s, b, c, f) in [(64, 64, 8, 500), (1, 4, 2, 3), (16, 16, 4, 1000)]:
+    for (s, b, c, f) in [(64, 64, 8, 500), (1, 4, 2, 3), (16, 16, 4, 1000),
+                         (256, 64, 2, 28)]:
         f_blk = choose_score_block(s, b, c, f)
-        assert f_blk <= -(-min(f, 128) // 8) * 8      # never pads past one block
+        assert f_blk <= -(-min(f, 32) // 8) * 8       # never pads past one block
         if f_blk > 8:  # above the halving floor the budget MUST hold
-            assert 6 * f_blk * s * b * c * 4 <= _VMEM_BUDGET
-            # ...and f_blk is maximal up to its caps (128, or F itself):
+            assert score_vmem_bytes(f_blk, s, b, c) <= _VMEM_BUDGET
+            # ...and f_blk is maximal up to its caps (32, or F itself):
             # doubling it would blow the budget.
             assert (
-                f_blk == 128
+                f_blk == 32
                 or f_blk == -(-f // 8) * 8
-                or 6 * (2 * f_blk) * s * b * c * 4 > _VMEM_BUDGET
+                or score_vmem_bytes(2 * f_blk, s, b, c) > _VMEM_BUDGET
             )
-    # The floor is only ever hit because even 8 features exceed the budget.
-    assert choose_score_block(64, 64, 8, 500) == 8
-    assert 6 * 16 * 64 * 64 * 8 * 4 > _VMEM_BUDGET
+    # The floor is only ever hit because even 16 features exceed the budget.
+    assert choose_score_block(256, 64, 8, 500) == 8
+    assert score_vmem_bytes(16, 256, 64, 8) > _VMEM_BUDGET
 
 
 @pytest.mark.parametrize("masked", [False, True])
