@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .binning import bin_dataset, apply_bins, fit_bins, fit_bins_blocked
+from .binning import apply_bins, fit_bins, fit_bins_blocked
 from .dimred import (
     dimension_reduction, dimension_reduction_streamed, random_feature_mask,
 )
@@ -32,6 +32,7 @@ from .engine import (
 from .forest import grow_forest, grow_forest_checkpointed
 from .gain import SplitScores, level_scores, resolve_split_backend, sibling_plan
 from .histograms import class_channels, regression_channels
+from .tracing import host_span
 from .types import Forest, ForestConfig
 from .voting import (
     oob_accuracy, oob_accuracy_streamed, oob_r2, oob_r2_streamed, predict,
@@ -129,6 +130,7 @@ def _checkpoint_manager(
     )
 
 
+@host_span("train")
 def train_prf(
     x: np.ndarray,
     y: np.ndarray,
@@ -181,6 +183,11 @@ def train_prf(
     is one block) and records them in ``model.quarantine``; ``None`` /
     ``"off"`` disables validation. On clean data the returned model is
     **bitwise identical** with validation on or off.
+
+    The call is the profiler host span ``prf.train``; on the resident
+    path each stage inside it is a span of its own (``prf.screen``,
+    ``prf.bin.fit``, ``prf.bin.apply``, ``prf.dsi``, ``prf.dimred``,
+    ``prf.grow``, ``prf.oob``; ``core/tracing``).
     """
     config = config.resolved(x.shape[1])
     if jax.process_count() > 1:
@@ -210,12 +217,13 @@ def train_prf(
     if bad_block_policy not in (None, "off"):
         from ..data.pipeline import DataIntegrityError, screen_blocks
 
-        blocks1, y_clean, cmasks, lmasks, report = screen_blocks(
-            [np.asarray(x)], np.asarray(y), policy=bad_block_policy,
-            n_features=x.shape[1],
-            n_classes=None if config.regression else config.n_classes,
-            regression=config.regression,
-        )
+        with host_span("screen"):
+            blocks1, y_clean, cmasks, lmasks, report = screen_blocks(
+                [np.asarray(x)], np.asarray(y), policy=bad_block_policy,
+                n_features=x.shape[1],
+                n_classes=None if config.regression else config.n_classes,
+                regression=config.regression,
+            )
         if not report.clean:
             if bad_block_policy == "quarantine":
                 raise DataIntegrityError(
@@ -235,64 +243,71 @@ def train_prf(
         from ..data.pipeline import sample_blocks
 
         nb_fit = config.sample_block if config.sample_block > 0 else 65536
-        edges = fit_bins_blocked(
-            sample_blocks(x, nb_fit), config.n_bins,
-            exclude_masks=(
-                None if cell_mask is None else sample_blocks(cell_mask, nb_fit)
-            ),
-        )
-        xb_np = np.asarray(apply_bins(jnp.asarray(x), jnp.asarray(edges)))
+        with host_span("bin.fit"):
+            edges = fit_bins_blocked(
+                sample_blocks(x, nb_fit), config.n_bins,
+                exclude_masks=(
+                    None if cell_mask is None else sample_blocks(cell_mask, nb_fit)
+                ),
+            )
     else:
-        xb_np, edges = bin_dataset(x, config.n_bins)
-    if cell_mask is not None:
-        xb_np = xb_np.copy()
-        xb_np[cell_mask] = 0                 # imputed cells -> bin 0
-    xb = jnp.asarray(xb_np)
+        with host_span("bin.fit"):
+            edges = fit_bins(x, config.n_bins)
+    with host_span("bin.apply"):
+        xb_np = np.asarray(apply_bins(jnp.asarray(x), jnp.asarray(edges)))
+        if cell_mask is not None:
+            xb_np = xb_np.copy()
+            xb_np[cell_mask] = 0                 # imputed cells -> bin 0
+        xb = jnp.asarray(xb_np)
     y = jnp.asarray(y)
     key = jax.random.PRNGKey(seed)
     k_boot, k_dim = jax.random.split(key)
 
-    weights = bootstrap_counts(k_boot, config.n_trees, x.shape[0])     # DSI §4.1.2
-    if label_mask is not None:
-        # Imputed-label samples get neutral (zero) weight in every tree.
-        weights = jnp.where(jnp.asarray(label_mask)[None, :], 0, weights)
+    with host_span("dsi"):
+        weights = bootstrap_counts(k_boot, config.n_trees, x.shape[0])  # DSI §4.1.2
+        if label_mask is not None:
+            # Imputed-label samples get neutral (zero) weight in every tree.
+            weights = jnp.where(jnp.asarray(label_mask)[None, :], 0, weights)
 
     feature_mask = None
-    if config.feature_mode == "importance" and not config.regression:
-        feature_mask = dimension_reduction(xb, y, weights, config, k_dim)  # §3.2
-    elif config.feature_mode == "random":
-        feature_mask = random_feature_mask(
-            k_dim, n_trees=config.n_trees, n_features=x.shape[1],
-            n_selected=config.n_selected,
-        )                                                              # §3.1 RF
+    with host_span("dimred"):
+        if config.feature_mode == "importance" and not config.regression:
+            feature_mask = dimension_reduction(xb, y, weights, config, k_dim)  # §3.2
+        elif config.feature_mode == "random":
+            feature_mask = random_feature_mask(
+                k_dim, n_trees=config.n_trees, n_features=x.shape[1],
+                n_selected=config.n_selected,
+            )                                                          # §3.1 RF
 
     y_grow = y if not config.regression else y.astype(jnp.float32)
-    if checkpoint_dir is not None or resume_from is not None:
-        forest = grow_forest_checkpointed(
-            xb, y_grow, weights, config, feature_mask,
-            manager=_checkpoint_manager(
-                checkpoint_dir, checkpoint_every, checkpoint_keep
-            ),
-            resume_from=resume_from, on_level=on_level,
-        )                                                              # §4.2
-    else:
-        forest = grow_forest(xb, y_grow, weights, config, feature_mask)  # §4.2
+    with host_span("grow"):
+        if checkpoint_dir is not None or resume_from is not None:
+            forest = grow_forest_checkpointed(
+                xb, y_grow, weights, config, feature_mask,
+                manager=_checkpoint_manager(
+                    checkpoint_dir, checkpoint_every, checkpoint_keep
+                ),
+                resume_from=resume_from, on_level=on_level,
+            )                                                          # §4.2
+        else:
+            forest = grow_forest(xb, y_grow, weights, config, feature_mask)  # §4.2
 
     if config.weighted_voting:                                         # §3.3
-        xb_o, y_o, w_o = xb, y, weights
-        if label_mask is not None:
-            # Zero-weight == out-of-bag, so imputed-label samples would
-            # otherwise score every tree against a made-up label — drop
-            # them from the Eq. 8 evaluation entirely.
-            kidx = jnp.asarray(np.flatnonzero(~label_mask))
-            xb_o = jnp.take(xb, kidx, axis=0)
-            y_o = jnp.take(y, kidx, axis=0)
-            w_o = jnp.take(weights, kidx, axis=1)
-        w = (
-            oob_r2(forest, xb_o, y_o.astype(jnp.float32), w_o)
-            if config.regression
-            else oob_accuracy(forest, xb_o, y_o, w_o)
-        )
+        with host_span("oob"):
+            xb_o, y_o, w_o = xb, y, weights
+            if label_mask is not None:
+                # Zero-weight == out-of-bag, so imputed-label samples would
+                # otherwise score every tree against a made-up label — drop
+                # them from the Eq. 8 evaluation entirely.
+                kidx = jnp.asarray(np.flatnonzero(~label_mask))
+                xb_o = jnp.take(xb, kidx, axis=0)
+                y_o = jnp.take(y, kidx, axis=0)
+                w_o = jnp.take(weights, kidx, axis=1)
+            w = (
+                oob_r2(forest, xb_o, y_o.astype(jnp.float32), w_o)
+                if config.regression
+                else oob_accuracy(forest, xb_o, y_o, w_o)
+            )
         forest = dataclasses.replace(forest, tree_weight=w)
 
     return PRFModel(forest=forest, bin_edges=edges, quarantine=report)

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .tracing import scope
+
 # Bin ids travel as uint8 end to end (histogram scatter, tree thresholds,
 # serving payloads) — more than 256 bins would silently wrap.
 MAX_BINS = 256
@@ -406,6 +408,7 @@ def fit_bins_blocked(
 
 
 @partial(jax.jit, static_argnames=())
+@scope("bin.apply")
 def apply_bins(x: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     """Digitize features into uint8 bin ids.
 

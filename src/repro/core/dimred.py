@@ -22,6 +22,7 @@ import numpy as np
 
 from .gain import multiway_gain_ratio, variable_importance
 from .histograms import class_channels, hist_feature_slab, level_histograms
+from .tracing import scope
 from .types import ForestConfig
 
 
@@ -63,6 +64,7 @@ def root_gain_ratios(
 
 
 @partial(jax.jit, static_argnames=("n_selected", "n_important"))
+@scope("dimred")
 def select_features(
     gr: jnp.ndarray, rng: jax.Array, *, n_selected: int, n_important: int
 ) -> jnp.ndarray:
@@ -85,6 +87,7 @@ def select_features(
 
 
 @partial(jax.jit, static_argnames=("n_trees", "n_features", "n_selected"))
+@scope("dimred")
 def random_feature_mask(
     rng: jax.Array, *, n_trees: int, n_features: int, n_selected: int
 ) -> jnp.ndarray:
@@ -110,6 +113,7 @@ def dimension_reduction(
 
 
 @partial(jax.jit, static_argnames=("n_bins", "backend"))
+@scope("dimred")
 def _root_hist_block(hist_acc, xb_b, base_b, w_b, *, n_bins, backend):
     slot0 = jnp.zeros_like(w_b, dtype=jnp.int32)
     return hist_acc + level_histograms(

@@ -18,6 +18,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .tracing import scope
+
 
 @partial(jax.jit, static_argnames=("n_trees", "n_samples"))
 def make_dsi(key: jax.Array, n_trees: int, n_samples: int) -> jnp.ndarray:
@@ -44,6 +46,7 @@ def oob_mask(counts: jnp.ndarray) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("n_trees", "n_samples"))
+@scope("dsi")
 def bootstrap_counts(key: jax.Array, n_trees: int, n_samples: int) -> jnp.ndarray:
     """Fused make_dsi + dsi_counts (never materializes the index table)."""
     dsi = make_dsi(key, n_trees, n_samples)

@@ -64,6 +64,7 @@ from .histograms import (
     sibling_segments,
 )
 from ..kernels import default_interpret
+from .tracing import scope
 from .types import Forest, ForestConfig, GrowthState
 
 
@@ -176,6 +177,7 @@ class LocalPlane(CollectivePlane):
 # ---------------------------------------------------------------------------
 
 
+@scope("tgr")
 def _level_hists(
     x_binned, base_channels, w_c, slot_c, config: ForestConfig,
     n_slots: Optional[int] = None,
@@ -242,10 +244,11 @@ def fused_level_scores(
         xb_s = jax.lax.dynamic_slice_in_dim(xb, f0, W, axis=1)
         mask_s = jax.lax.dynamic_slice_in_dim(mask, f0, W, axis=1)
         hist = _level_hists(xb_s, base_channels, weights, sample_slot, config)
-        return split_scan_block(
-            hist, mask_s, carry, f0,
-            regression=config.regression, interpret=interpret,
-        )
+        with scope("tns"):
+            return split_scan_block(
+                hist, mask_s, carry, f0,
+                regression=config.regression, interpret=interpret,
+            )
 
     carry = jax.lax.fori_loop(0, Fp // W, slab, init_carry(tc, S, C))
     scores = SplitScores(*carry)
@@ -295,9 +298,10 @@ def chunked_level_scores(
         hist = _level_hists(x_binned, base_channels, w_c, slot_c, config)
         if hist_reduce is not None:
             hist = hist_reduce(hist)     # psum over the sample axis (T_GR combine)
-        return level_scores(
-            hist, mask_c, regression=config.regression, backend=split_be
-        )
+        with scope("tns"):
+            return level_scores(
+                hist, mask_c, regression=config.regression, backend=split_be
+            )
 
     if tc >= k:
         return score_chunk(weights, sample_slot, feature_mask)
@@ -415,10 +419,11 @@ def fused_reuse_level_scores(
         ch_s = jax.lax.dynamic_slice_in_dim(cache_h, f0, W, axis=2)
         packed_s = _level_hists(xb_s, base_channels, weights, seg, config, n_slots=R)
         hist_s = sibling_expand(packed_s, ch_s, cache["perm"], cache["parent"], S)
-        carry = split_scan_block(
-            hist_s, mask_s, carry, f0,
-            regression=config.regression, interpret=interpret,
-        )
+        with scope("tns"):
+            carry = split_scan_block(
+                hist_s, mask_s, carry, f0,
+                regression=config.regression, interpret=interpret,
+            )
         h2 = jax.lax.dynamic_update_slice_in_dim(h2, hist_s, f0, axis=2)
         return carry, h2
 
@@ -454,14 +459,16 @@ def reuse_expand_scores(
         packed_h, cache["hist"], cache["perm"], cache["parent"], S
     )
     perm = sibling_perm(cache["small_right"], S)
-    scores_r, n_r = level_scores(
-        hist2, feature_mask, regression=config.regression,
-        backend=resolve_split_backend(config.split_backend),
-    )
+    with scope("tns"):
+        scores_r, n_r = level_scores(
+            hist2, feature_mask, regression=config.regression,
+            backend=resolve_split_backend(config.split_backend),
+        )
     scores = jax.tree_util.tree_map(partial(_permute_rows, perm), scores_r)
     return scores, _permute_rows(perm, n_r), hist2, perm
 
 
+@scope("task_group")
 def reuse_level_task_group(
     x_binned, base_channels, weights, sample_slot, slot_node, cache,
     config: ForestConfig, plane: CollectivePlane,
@@ -559,6 +566,7 @@ def init_growth_state(
     )
 
 
+@scope("task_group")
 def level_task_group(
     x_binned, base_channels, weights, sample_slot, slot_node,
     config: ForestConfig, plane: CollectivePlane,
@@ -580,6 +588,7 @@ def level_task_group(
     return plane.merge_winners(scores_loc, n_loc)
 
 
+@scope("plan_write")
 def plan_level(
     scores: SplitScores, n_node: jnp.ndarray, slot_node: jnp.ndarray,
     config: ForestConfig, level: jnp.ndarray,
@@ -600,6 +609,7 @@ def plan_level(
     return split_rank, is_split, child_base
 
 
+@scope("plan_write")
 def write_level(
     forest: Forest, slot_node, split_rank, is_split, child_base,
     scores: SplitScores, config: ForestConfig,
@@ -638,6 +648,7 @@ def write_level(
     )
 
 
+@scope("route")
 def route_level(
     x_binned, sample_slot, split_rank, scores: SplitScores,
     plane: CollectivePlane,
@@ -697,15 +708,17 @@ def stream_block_step(
     else:
         slots = sibling_segments(slot_b, small_right)
         n_slots = config.max_splits_per_level
-    h = level_histograms(
-        xb_b, base_b, w_lvl, slots,
-        n_slots=n_slots, n_bins=config.n_bins,
-        packed=config.packed_hist and not config.regression,
-        backend=config.hist_backend,
-    )
+    with scope("tgr"):
+        h = level_histograms(
+            xb_b, base_b, w_lvl, slots,
+            n_slots=n_slots, n_bins=config.n_bins,
+            packed=config.packed_hist and not config.regression,
+            backend=config.hist_backend,
+        )
     return hist_acc + h, slot_b
 
 
+@scope("plan_write")
 def next_frontier(is_split, child_base, n_slots: int) -> jnp.ndarray:
     """Next level's frontier: this level's children, densely packed."""
     j = jnp.arange(n_slots)[None, :]
@@ -761,6 +774,10 @@ def level_step(
     is refreshed with this level's paired histograms plus the next
     level's small-side plan; the branch is on pytree *structure*, so
     both modes are one traced computation each.
+
+    Each phase runs under a profiler scope (``core/tracing``):
+    ``prf.task_group`` (``prf.tgr`` and ``prf.tns`` around the two
+    kernels inside it), ``prf.plan_write`` and ``prf.route``.
     """
     if state.hist_cache is None:
         scores, n_node = level_task_group(
@@ -784,19 +801,21 @@ def level_step(
         x_binned, state.sample_slot, split_rank, scores, plane
     )
     slot_node = next_frontier(is_split, child_base, config.frontier)
-    if new_cache is not None:
-        parent, small_right = sibling_plan(
-            scores, split_rank, is_split,
-            n_ranks=config.max_splits_per_level,
-            regression=config.regression,
-        )
-        new_cache = dict(new_cache, parent=parent, small_right=small_right)
+    with scope("plan_write"):
+        if new_cache is not None:
+            parent, small_right = sibling_plan(
+                scores, split_rank, is_split,
+                n_ranks=config.max_splits_per_level,
+                regression=config.regression,
+            )
+            new_cache = dict(new_cache, parent=parent, small_right=small_right)
+        level = state.level + 1
     return GrowthState(
         forest=forest,
         slot_node=slot_node,
         sample_slot=sample_slot,
         rng=state.rng,
-        level=state.level + 1,
+        level=level,
         hist_cache=new_cache,
     )
 

@@ -29,6 +29,7 @@ from .engine import (  # noqa: F401  (re-exported: training internals)
     reuse_level_task_group,
 )
 from .histograms import class_channels, regression_channels
+from .tracing import scope
 from .types import Forest, ForestConfig
 
 
@@ -89,6 +90,7 @@ def _grow_forest_impl(x_binned, y, weights, config, feature_mask):
 
 
 @jax.jit
+@scope("walk")
 def route_to_leaves(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
     """Leaf pool-id of every sample under every tree. Returns [k, N] int32."""
     k = forest.feature.shape[0]
