@@ -29,7 +29,9 @@ from .engine import (
     next_frontier, plan_level, resolve_hist_reuse, reuse_expand_scores,
     stream_block_step, write_level,
 )
-from .forest import grow_forest, grow_forest_checkpointed
+from .forest import (
+    grow_forest, grow_forest_checkpointed, grown_leaves, with_leaves,
+)
 from .gain import SplitScores, level_scores, resolve_split_backend, sibling_plan
 from .histograms import class_channels, regression_channels
 from .tracing import host_span
@@ -303,6 +305,11 @@ def train_prf(
                 xb_o = jnp.take(xb, kidx, axis=0)
                 y_o = jnp.take(y, kidx, axis=0)
                 w_o = jnp.take(weights, kidx, axis=1)
+                leaves = grown_leaves(forest, xb)
+                if leaves is not None:
+                    forest = with_leaves(
+                        forest, xb_o, jnp.take(leaves, kidx, axis=1)
+                    )
             w = (
                 oob_r2(forest, xb_o, y_o.astype(jnp.float32), w_o)
                 if config.regression

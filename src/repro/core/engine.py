@@ -605,8 +605,13 @@ def plan_level(
     )
     split_rank = _rank_splits(scores.gain_ratio, valid, n_max)    # [k, S]
     is_split = split_rank >= 0
-    child_base = 1 + 2 * n_max * level
-    return split_rank, is_split, child_base
+    return split_rank, is_split, child_band(level, n_max)
+
+
+def child_band(level, n_max: int):
+    """First pool id of the children written at ``level``: level L's
+    children fill ``[1 + 2*n_max*L, 1 + 2*n_max*(L+1))``."""
+    return 1 + 2 * n_max * level
 
 
 @scope("plan_write")
@@ -716,6 +721,20 @@ def stream_block_step(
             backend=config.hist_backend,
         )
     return hist_acc + h, slot_b
+
+
+@scope("route")
+def settle_leaves(node, sample_slot, level, config: ForestConfig) -> jnp.ndarray:
+    """Pool node of every sample once ``level`` has been routed.
+
+    A sample routed to child slot ``j`` sits in pool node
+    ``child_band(level) + j`` (``next_frontier`` gives slot ``j`` that
+    node); a sample parked at -1 stays in the node it was in, which is
+    then a leaf. Carried over every level from the root (node 0), this
+    is the leaf ``forest.route_to_leaves`` finds by walking the finished
+    forest, without a gather."""
+    base = child_band(level, config.max_splits_per_level)
+    return jnp.where(sample_slot >= 0, base + sample_slot, node)
 
 
 @scope("plan_write")
@@ -885,7 +904,7 @@ def grow_checkpointed(
     return finalize_forest(state.forest)
 
 
-def grow(
+def grow_with_leaves(
     x_binned: jnp.ndarray,        # [N, F] uint8 (local shard in distributed mode)
     base_channels: jnp.ndarray,   # [N, C]
     weights: jnp.ndarray,         # [k, N] DSI in-bag multiplicities
@@ -893,7 +912,7 @@ def grow(
     plane: CollectivePlane,
     *,
     rng: Optional[jnp.ndarray] = None,
-) -> Forest:
+) -> tuple[Forest, jnp.ndarray]:
     """Level-synchronous growth over ``plane`` — the unified engine.
 
     A ``lax.while_loop`` threads the full ``GrowthState`` carry through
@@ -901,6 +920,11 @@ def grow(
     soon as every tree's frontier is empty (the paper's schedulers
     dispatching no tasks for finished trees), which skips entire levels
     of histogram + routing work for shallow-converging forests.
+
+    Routing moves every sample, in-bag or not, so the loop also carries
+    each sample's pool node (``settle_leaves``). Returns the forest and
+    the ``[k, N]`` int32 leaf of every sample under every tree: equal to
+    ``forest.route_to_leaves(forest, x_binned)``, with no second walk.
     """
     depth = config.max_depth
     state = init_growth_state(
@@ -908,14 +932,35 @@ def grow(
         n_features=x_binned.shape[1],
     )
 
-    def cond(state: GrowthState):
+    def cond(carry):
+        state, _ = carry
         more = state.level < depth
         if config.early_exit:
             more = more & jnp.any(state.slot_node >= 0)
         return more
 
-    def body(state: GrowthState) -> GrowthState:
-        return level_step(x_binned, base_channels, weights, state, config, plane)
+    def body(carry):
+        state, node = carry
+        new = level_step(x_binned, base_channels, weights, state, config, plane)
+        return new, settle_leaves(node, new.sample_slot, state.level, config)
 
-    state = jax.lax.while_loop(cond, body, state)
-    return finalize_forest(state.forest)
+    state, node = jax.lax.while_loop(
+        cond, body, (state, jnp.zeros_like(state.sample_slot))
+    )
+    return finalize_forest(state.forest), node
+
+
+def grow(
+    x_binned: jnp.ndarray,
+    base_channels: jnp.ndarray,
+    weights: jnp.ndarray,
+    config: ForestConfig,
+    plane: CollectivePlane,
+    *,
+    rng: Optional[jnp.ndarray] = None,
+) -> Forest:
+    """``grow_with_leaves`` without the leaves (the mesh trainer's loop;
+    the compiler drops the unused leaf carry)."""
+    return grow_with_leaves(
+        x_binned, base_channels, weights, config, plane, rng=rng
+    )[0]

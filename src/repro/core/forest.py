@@ -16,6 +16,7 @@ Prediction (``route_to_leaves`` + the fused traversal path) stays here.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional
 
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from .engine import (  # noqa: F401  (re-exported: training internals)
     LocalPlane, _gather_feature_bins, _rank_splits, _safe_mean,
     chunked_level_scores, fused_level_scores, fused_reuse_level_scores,
-    grow, grow_checkpointed, init_forest, resolve_hist_reuse,
+    grow, grow_checkpointed, grow_with_leaves, init_forest, resolve_hist_reuse,
     reuse_level_task_group,
 )
 from .histograms import class_channels, regression_channels
@@ -40,8 +41,34 @@ def grow_forest(
     config: ForestConfig,
     feature_mask: Optional[jnp.ndarray] = None,   # [k, F] bool (dim-reduction)
 ) -> Forest:
-    """Train k trees level-synchronously. Pure function of its inputs."""
-    return _grow_forest_impl(x_binned, y, weights, config, feature_mask)
+    """Train k trees level-synchronously. Pure function of its inputs.
+
+    When ``config.weighted_voting`` asks for OOB weights, the forest
+    keeps the leaf every row of ``x_binned`` reached in the growth loop
+    (``grown_leaves``), so ``voting.oob_accuracy`` / ``oob_r2`` on these
+    rows read it instead of walking the forest again. The record is not
+    part of the pytree: ``dataclasses.replace`` and every JAX transform
+    drop it. The leaves ride on the forest, not in the return value, so
+    that growth and OOB scoring stay two calls of ``train_prf`` that
+    can each be replaced on its own.
+    """
+    forest, leaves = _grow_forest_impl(x_binned, y, weights, config, feature_mask)
+    return forest if leaves is None else with_leaves(forest, x_binned, leaves)
+
+
+def with_leaves(forest: Forest, x_binned: jnp.ndarray, leaves: jnp.ndarray) -> Forest:
+    """A copy of ``forest`` that records ``leaves`` ([k, N] int32) as the
+    leaf of every row of ``x_binned`` (``grown_leaves``)."""
+    out = dataclasses.replace(forest)
+    out._leaves_of = (x_binned, leaves)
+    return out
+
+
+def grown_leaves(forest: Forest, x_binned: jnp.ndarray) -> Optional[jnp.ndarray]:
+    """The leaves ``forest`` recorded for exactly this ``x_binned`` array
+    (``with_leaves``), else None: ``route_to_leaves`` must walk."""
+    rec = getattr(forest, "_leaves_of", None)
+    return rec[1] if rec is not None and rec[0] is x_binned else None
 
 
 def grow_forest_checkpointed(
@@ -76,12 +103,18 @@ def grow_forest_checkpointed(
 
 @partial(jax.jit, static_argnames=("config",))
 def _grow_forest_impl(x_binned, y, weights, config, feature_mask):
+    """The grow program: ``(forest, leaves)``, the ``[k, N]`` leaves of
+    ``engine.grow_with_leaves`` when the forest will be OOB-weighted,
+    else None (and the compiler drops the carry)."""
     base = (
         regression_channels(y)
         if config.regression
         else class_channels(y, config.n_classes)
     )
-    return grow(x_binned, base, weights, config, LocalPlane(feature_mask))
+    forest, leaves = grow_with_leaves(
+        x_binned, base, weights, config, LocalPlane(feature_mask)
+    )
+    return forest, (leaves if config.weighted_voting else None)
 
 
 # ---------------------------------------------------------------------------
@@ -120,15 +153,14 @@ def predict_proba_trees(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
     return counts / jnp.maximum(counts.sum(-1, keepdims=True), 1e-38)
 
 
-def predict_label_trees(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
-    """Per-tree predicted class ``argmax_c h_i(x)``. Returns [k, N] int32.
+def labels_at_leaves(forest: Forest, leaves: jnp.ndarray) -> jnp.ndarray:
+    """Predicted class ``argmax_c h_i`` at each of ``leaves`` ([k, N]).
 
     The argmax is taken per pool node and then gathered at the leaves,
     which equals the argmax of ``predict_proba_trees`` without ever
     building the ``[k, N, C]`` tensor (on a TPU its small class axis
     pads to a full lane tile, 64x at C=2).
     """
-    leaves = route_to_leaves(forest, x_binned)
     counts = forest.class_counts
     probs = counts / jnp.maximum(counts.sum(-1, keepdims=True), 1e-38)
     return jnp.take_along_axis(

@@ -28,8 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .forest import (
-    fused_vote_scores, predict_label_trees, predict_proba_trees, predict_value_trees,
+    fused_vote_scores, grown_leaves, labels_at_leaves, predict_proba_trees,
+    predict_value_trees, route_to_leaves,
 )
+from .tracing import scope
 from .types import Forest
 
 PREDICT_BACKENDS = ("auto", "pallas", "xla")
@@ -46,23 +48,51 @@ def resolve_predict_backend(backend: str) -> str:
     return backend
 
 
+def _leaves(forest: Forest, x_binned: jnp.ndarray) -> jnp.ndarray:
+    """Leaf of every row of ``x_binned``: the growth loop's own, when
+    ``forest`` recorded them for these rows (``forest.grow_forest``),
+    else a walk of the forest."""
+    leaves = grown_leaves(forest, x_binned)
+    return route_to_leaves(forest, x_binned) if leaves is None else leaves
+
+
 def oob_accuracy(
     forest: Forest, x_binned: jnp.ndarray, y: jnp.ndarray, weights: jnp.ndarray
 ) -> jnp.ndarray:
-    """Eq. (8): CA_i = #correct / (#correct + #error) over OOB_i.
+    """Eq. (8): CA_i = #correct / (#correct + #error) over OOB_i
+    (``oob_accuracy_at_leaves`` at the leaves of ``x_binned``).
 
     Args:
       weights: [k, N] in-bag multiplicities (0 => sample is OOB for tree).
-    Returns: [k] float32 accuracies. A tree whose OOB set is empty (every
-    sample in-bag — possible under the DSI bootstrap) has no evidence
-    either way and gets the **neutral prior 0.5**, never a degenerate
-    0/0.
+    Returns: [k] float32 accuracies; a tree with an empty OOB set gets
+    the neutral prior 0.5.
     """
-    pred = predict_label_trees(forest, x_binned)           # [k, N]
-    oob = (weights == 0.0).astype(jnp.float32)             # [k, N]
+    return oob_accuracy_at_leaves(forest, _leaves(forest, x_binned), y, weights)
+
+
+@scope("oob")
+def _oob_counts(forest: Forest, leaves, y, w):
+    """Eq. (8)'s counts at the leaves: (#correct, #OOB) per tree."""
+    pred = labels_at_leaves(forest, leaves)                # [k, N]
+    oob = (w == 0.0).astype(jnp.float32)                   # [k, N]
     correct = jnp.sum(oob * (pred == y[None]).astype(jnp.float32), axis=1)
-    total = jnp.sum(oob, axis=1)
+    return correct, jnp.sum(oob, axis=1)
+
+
+def _accuracy(correct, total):
+    """A tree whose OOB set is empty (every sample in-bag — possible
+    under the DSI bootstrap) has no evidence either way and gets the
+    **neutral prior 0.5**, never a degenerate 0/0."""
     return jnp.where(total > 0, correct / jnp.maximum(total, 1.0), 0.5)
+
+
+@jax.jit
+def oob_accuracy_at_leaves(
+    forest: Forest, leaves: jnp.ndarray, y: jnp.ndarray, weights: jnp.ndarray
+) -> jnp.ndarray:
+    """Eq. (8) with each sample's leaf given: ``leaves`` [k, N] int32 pool
+    ids, ``weights`` [k, N] in-bag multiplicities (0 => OOB). [k] f32."""
+    return _accuracy(*_oob_counts(forest, leaves, y, weights))
 
 
 def oob_r2(forest, x_binned, y, weights):
@@ -76,18 +106,20 @@ def oob_r2(forest, x_binned, y, weights):
     non-neutral weight.
 
     The sample reduction runs on HOST in float64 over per-sample f32
-    moment terms (``_r2_block_terms`` — the same jitted kernel the
+    moment terms (``_r2_leaf_terms`` — the same jitted kernel the
     streamed path folds per block), then one final float32 cast. That
     makes ``oob_r2`` and ``oob_r2_streamed`` **bit-identical**: the
     per-sample terms are batch-shape independent, and the float64
     accumulations (one-shot pairwise here, Neumaier-compensated across
     blocks there) agree to well under a float32 ulp before the cast.
+    Like ``oob_accuracy``, it reads the growth loop's leaves when the
+    forest recorded them for ``x_binned``.
     """
     y32 = jnp.asarray(y, jnp.float32)
     w32 = jnp.asarray(weights, jnp.float32)
     sum_y, total = _r2_mean_stats(y32, w32)
     mean = sum_y / jnp.maximum(total, 1.0)
-    err_t, var_t = _r2_block_terms(forest, x_binned, y32, w32, mean)
+    err_t, var_t = _r2_leaf_terms(forest, _leaves(forest, x_binned), y32, w32, mean)
     return _r2_finalize(
         np.asarray(err_t, np.float64).sum(axis=1),
         np.asarray(var_t, np.float64).sum(axis=1),
@@ -151,10 +183,7 @@ def _block_feeder(x_binned, sample_block, prefetch, *, what,
 @jax.jit
 def _oob_block_counts(forest: Forest, xb_b, y_b, w_b):
     """One block's contribution to Eq. (8): (#correct, #OOB) per tree."""
-    pred = predict_label_trees(forest, xb_b)               # [k, Nb]
-    oob = (w_b == 0.0).astype(jnp.float32)
-    correct = jnp.sum(oob * (pred == y_b[None]).astype(jnp.float32), axis=1)
-    return correct, jnp.sum(oob, axis=1)
+    return _oob_counts(forest, route_to_leaves(forest, xb_b), y_b, w_b)
 
 
 def oob_accuracy_streamed(
@@ -184,10 +213,11 @@ def oob_accuracy_streamed(
             )
             correct, total = correct + c, total + t
             o += n
-    return jnp.where(total > 0, correct / jnp.maximum(total, 1.0), 0.5)
+    return _accuracy(correct, total)
 
 
 @jax.jit
+@scope("oob")
 def _r2_mean_stats(y, w):
     """The OOB mean's sufficient statistics — needs y/weights only, so
     it runs on the full [k, N] arrays exactly like the resident path
@@ -197,19 +227,26 @@ def _r2_mean_stats(y, w):
 
 
 @jax.jit
-def _r2_block_terms(forest: Forest, xb_b, y_b, w_b, mean):
-    """Per-sample OOB squared-error / variance terms for one block,
-    [k, Nb] each. Tree traversal and the moment arithmetic are
-    per-sample elementwise, so each term is bit-identical whether the
-    block is the whole dataset or one slice of it — the same
-    batch-shape independence the streamed predict parity rests on. The
-    sample reduction deliberately does NOT happen on device: both
-    ``oob_r2`` paths reduce the terms on host in float64."""
-    vals = predict_value_trees(forest, xb_b)               # [k, Nb]
+@scope("oob")
+def _r2_leaf_terms(forest: Forest, leaves, y_b, w_b, mean):
+    """Per-sample OOB squared-error / variance terms at the samples'
+    leaves, [k, Nb] each. The moment arithmetic is per-sample
+    elementwise, so each term is bit-identical whether the block is the
+    whole dataset or one slice of it — the same batch-shape independence
+    the streamed predict parity rests on. The sample reduction
+    deliberately does NOT happen on device: both ``oob_r2`` paths
+    reduce the terms on host in float64."""
+    vals = jnp.take_along_axis(forest.value, leaves, axis=1)   # [k, Nb]
     oob = (w_b == 0.0).astype(jnp.float32)
     err_t = oob * (vals - y_b[None]) ** 2
     var_t = oob * (y_b[None] - mean[:, None]) ** 2
     return err_t, var_t
+
+
+@jax.jit
+def _r2_block_terms(forest: Forest, xb_b, y_b, w_b, mean):
+    """``_r2_leaf_terms`` at the leaves of one block's rows."""
+    return _r2_leaf_terms(forest, route_to_leaves(forest, xb_b), y_b, w_b, mean)
 
 
 def _neumaier_add(s: np.ndarray, c: np.ndarray, x: np.ndarray) -> None:

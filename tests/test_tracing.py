@@ -2,7 +2,7 @@
 
 * every device scope reaches the ``op_name`` metadata of the compiled
   program that runs it (grow with sibling reuse on and off, binning,
-  DSI, dimension reduction and the OOB tree walk);
+  DSI, dimension reduction, the tree walk and OOB scoring at leaves);
 * every primitive of the grow program's level loop runs under a
   ``prf.`` scope. Checked on the jaxpr, which is what the program hands
   the compiler: the compiler's own layout copies and bitcast-rooted
@@ -25,6 +25,7 @@ from repro.core.dimred import random_feature_mask, select_features
 from repro.core.dsi import bootstrap_counts
 from repro.core.engine import init_forest
 from repro.core.forest import _grow_forest_impl, route_to_leaves
+from repro.core.voting import oob_accuracy_at_leaves
 
 N, F, K, B = 256, 6, 4, 8
 ENGINE_SCOPES = {"prf.task_group", "prf.tgr", "prf.tns", "prf.plan_write", "prf.route"}
@@ -77,6 +78,11 @@ def _lowered(program: str):
     if program == "dimred.random":
         return random_feature_mask.lower(key, n_trees=K, n_features=F, n_selected=3)
     forest = init_forest(_cfg("off"))
+    if program == "oob":
+        return oob_accuracy_at_leaves.lower(
+            forest, jnp.zeros((K, N), jnp.int32), jnp.zeros((N,), jnp.int32),
+            jnp.ones((K, N), jnp.float32),
+        )
     return route_to_leaves.lower(forest, jnp.zeros((N, F), jnp.uint8))
 
 
@@ -86,6 +92,7 @@ def _lowered(program: str):
     ("dimred.select", "prf.dimred"),
     ("dimred.random", "prf.dimred"),
     ("walk", "prf.walk"),
+    ("oob", "prf.oob"),
 ])
 def test_program_carries_its_scope(program, scope):
     assert scope in _scopes(_lowered(program).compile().as_text())
