@@ -33,7 +33,7 @@ needs no cross-shard index exchange. Noted as an adaptation in DESIGN.md.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional, Sequence
 
 import jax
@@ -54,6 +54,7 @@ from .gain import (
     sibling_plan,
 )
 from .histograms import class_channels, level_histograms, regression_channels
+from .tracing import host_span, scope
 from .types import Forest, ForestConfig
 
 
@@ -119,6 +120,9 @@ class MeshPlane(CollectivePlane):
     ids first. ``broadcast_route``: the winning feature lives on exactly
     one feature shard; it computes the go-right bit, a masked psum
     broadcasts it (the paper's "result distributed to all slaves").
+    Each hook runs under a profiler scope (``core/tracing``):
+    ``prf.mesh.combine`` (the histogram and root-count reductions),
+    ``prf.mesh.merge`` and ``prf.mesh.route`` (the go-right psum).
     """
 
     def __init__(
@@ -146,16 +150,22 @@ class MeshPlane(CollectivePlane):
             self.level_mask = jax.lax.dynamic_slice_in_dim(
                 mask_src, self.didx * self.fl_sub, self.fl_sub, 1
             )
-            self.combine_hist = lambda h: jax.lax.psum_scatter(
-                h, self.sample_axes[0], scatter_dimension=2, tiled=True
+            self.combine_hist = scope("mesh.combine")(
+                lambda h: jax.lax.psum_scatter(
+                    h, self.sample_axes[0], scatter_dimension=2, tiled=True
+                )
             )
         else:
             self.level_mask = mask_loc
-            self.combine_hist = lambda h: jax.lax.psum(h, self.sample_axes)
+            self.combine_hist = scope("mesh.combine")(
+                lambda h: jax.lax.psum(h, self.sample_axes)
+            )
 
+    @scope("mesh.combine")
     def reduce_root(self, root_counts):
         return jax.lax.psum(root_counts, self.sample_axes)
 
+    @scope("mesh.merge")
     def merge_winners(self, scores, n_node):
         if self.use_rs:
             f_glob = scores.feature + self.midx * self.Fl + self.didx * self.fl_sub
@@ -181,7 +191,8 @@ class MeshPlane(CollectivePlane):
         go_loc = jnp.where(
             f_shard == self.midx, (bins_i > thr_i).astype(jnp.int32), 0
         )
-        return jax.lax.psum(go_loc, self.feature_axis)
+        with scope("mesh.route"):
+            return jax.lax.psum(go_loc, self.feature_axis)
 
 
 def _grow_sharded(
@@ -1018,6 +1029,7 @@ def predict_streamed_sharded(
     return np.concatenate(out)
 
 
+@scope("walk")
 def _route_sharded(forest: Forest, xb_loc, *, feature_axis: str):
     """route_to_leaves when features are sharded over `feature_axis`."""
     k = forest.feature.shape[0]
@@ -1043,6 +1055,7 @@ def _route_sharded(forest: Forest, xb_loc, *, feature_axis: str):
     return leaves
 
 
+@scope("dimred")
 def _dimred_sharded(xb_loc, base_loc, w_loc, config, key, *, sample_axes, feature_axis):
     """Distributed Alg. 3.1: local GR + global VI ranking."""
     k, Nl = w_loc.shape
@@ -1065,6 +1078,7 @@ def _dimred_sharded(xb_loc, base_loc, w_loc, config, key, *, sample_axes, featur
     return jax.lax.dynamic_slice_in_dim(mask, midx * Fl, Fl, axis=1)
 
 
+@scope("oob")
 def _oob_weights_sharded(forest, xb_loc, y_loc, w_loc, *, sample_axes, feature_axis):
     """Eq. (8) with samples and features sharded."""
     leaves = _route_sharded(forest, xb_loc, feature_axis=feature_axis)
@@ -1196,6 +1210,23 @@ def predict_sharded(forest: Forest, x_binned, mesh, *,
 # ---------------------------------------------------------------------------
 
 
+@lru_cache(maxsize=None)
+def _sketch_exchange(mesh: Mesh, axes: tuple):
+    """The jitted ``all_gather`` of every shard's sketch payload over
+    ``axes``, built once per mesh: a jit built per call would trace and
+    compile again at every fit."""
+    def _exchange(p_loc):
+        g = p_loc  # [1, F, width + 1, 4] per shard
+        for a in reversed(axes):
+            g = jax.lax.all_gather(g, a, axis=0, tiled=True)
+        return g
+
+    return jax.jit(jax.shard_map(
+        _exchange, mesh=mesh, in_specs=(P(axes),), out_specs=P(), check_vma=False,
+    ))
+
+
+@host_span("bin.fit")
 def fit_bins_sharded(
     x,
     n_bins: int,
@@ -1295,12 +1326,6 @@ def fit_bins_sharded(
             ord(np.dtype(st["value_dtype"]).char)
         )
 
-    def _exchange(p_loc):
-        g = p_loc  # [1, F, width + 1, 4] per shard
-        for a in reversed(axes):
-            g = jax.lax.all_gather(g, a, axis=0, tiled=True)
-        return g
-
     gshape = (n_shards, n_features, width + 1, 4)
     p_dev = (
         runtime.put(
@@ -1310,12 +1335,7 @@ def fit_bins_sharded(
         )
         if runtime is not None else jnp.asarray(payloads)
     )
-    gathered = jax.jit(jax.shard_map(
-        _exchange, mesh=mesh,
-        in_specs=(P(axes),),
-        out_specs=P(),
-        check_vma=False,
-    ))(p_dev)
+    gathered = _sketch_exchange(mesh, axes)(p_dev)
     gathered = np.ascontiguousarray(np.asarray(jax.device_get(gathered)))
 
     merged = None

@@ -411,6 +411,15 @@ def test_fit_bins_sharded_matches_blocked_and_exact():
                               sample_axes=("data", "model"))
         b3 = fit_bins_blocked([x[i:i + 100] for i in range(0, 1000, 100)], 16)
         assert np.array_equal(e3, b3)
+
+        # A repeated fit on the same mesh compiles nothing.
+        import jax
+        compiles = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, _d, **_kw: compiles.append(event)
+            if event == "/jax/core/compile/backend_compile_duration" else None)
+        e4 = fit_bins_sharded(x, 32, mesh, sample_block=170)
+        assert np.array_equal(e4, e_sh) and not compiles, compiles
         print("SHARDED_BINNING_OK")
     """)
     out = subprocess.run(

@@ -7,6 +7,9 @@
   ``prf.`` scope. Checked on the jaxpr, which is what the program hands
   the compiler: the compiler's own layout copies and bitcast-rooted
   fusions carry no metadata at all;
+* the mesh trainer (``make_prf_train_fn``) carries the mesh plane's
+  scopes, and every collective of its level loop runs under a
+  ``prf.mesh.`` scope;
 * ``train_prf`` writes its eight host spans in call order, nested under
   ``prf.train``, into a profiler trace.
 """
@@ -20,8 +23,11 @@ import numpy as np
 import pytest
 
 from repro.core import ForestConfig, train_prf
+from jax.sharding import Mesh
+
 from repro.core.binning import apply_bins
 from repro.core.dimred import random_feature_mask, select_features
+from repro.core.distributed import make_prf_train_fn
 from repro.core.dsi import bootstrap_counts
 from repro.core.engine import init_forest
 from repro.core.forest import _grow_forest_impl, route_to_leaves
@@ -138,6 +144,50 @@ def test_every_level_loop_primitive_is_scoped(reuse):
     # feature-slab and tree-chunk loops) sit under its scopes.
     body = next(_level_loops(closed.jaxpr))
     assert _unscoped(body) == []
+
+
+MESH_SCOPES = {"prf.mesh.combine", "prf.mesh.merge", "prf.mesh.route", "prf.walk",
+               "prf.dimred", "prf.dsi", "prf.oob"}
+COLLECTIVES = {"psum", "all_gather", "reduce_scatter", "all_to_all", "ppermute", "pmax",
+               "pmin"}
+
+
+def _mesh_train(reduce: str):
+    """The mesh trainer on a (data=1, model=1) mesh of this process's
+    device, and its arguments: the same program a larger mesh runs, with
+    every collective over axes of size one."""
+    cfg = ForestConfig(n_trees=K, max_depth=3, n_bins=B, n_classes=2, hist_reuse="off",
+                       hist_reduce=reduce)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    fn, _ = make_prf_train_fn(cfg, mesh)
+    return fn, (jnp.zeros((N, F), jnp.uint8), jnp.zeros((N,), jnp.int32),
+                jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("reduce", ["psum", "psum_scatter"])
+def test_mesh_program_carries_mesh_scopes(reduce):
+    fn, args = _mesh_train(reduce)
+    assert MESH_SCOPES <= _scopes(fn.lower(*args).compile().as_text())
+
+
+def _equations(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append(eqn)
+        for j in _sub_jaxprs(eqn):
+            out += _equations(j)
+    return out
+
+
+@pytest.mark.parametrize("reduce", ["psum", "psum_scatter"])
+def test_every_mesh_level_collective_is_under_a_mesh_scope(reduce):
+    fn, args = _mesh_train(reduce)
+    body = next(_level_loops(jax.make_jaxpr(fn)(*args).jaxpr))
+    stacks = [str(e.source_info.name_stack) for e in _equations(body)
+              if e.primitive.name in COLLECTIVES]
+    # The histogram combine, the winner merge's gathers and psums, the route bit.
+    assert len(stacks) >= 4
+    assert all("prf.mesh." in s for s in stacks), stacks
 
 
 def _host_spans(trace_dir: str) -> list:
