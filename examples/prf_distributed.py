@@ -175,7 +175,7 @@ def main():
 
     mesh = make_mesh((args.data, args.model), ("data", "model"))
     print(f"mesh: data={args.data} x model={args.model}")
-    # Bin edges from per-shard quantile sketches merged over the mesh —
+    # Bin edges from per-shard quantile sketches merged in shard order —
     # no single host ever takes a full pass over the raw source.
     edges = fit_bins_sharded(xtr, cfg.n_bins, mesh, sample_block=512)
     xb = np.asarray(apply_bins(jnp.asarray(xtr), jnp.asarray(edges)))

@@ -24,7 +24,9 @@ Two fitting paths share one edge contract (``[F, B-1]`` float64, ascending):
   the per-attribute distributed-quantile approach of "Exact Distributed
   Training: Random Forest with Billions of Examples" (arXiv 1804.06755);
   ``core/distributed.fit_bins_sharded`` runs one sketch per mesh data shard
-  and merges them host-side.
+  and merges them host-side in shard order: in memory when one process
+  holds every shard, after a lossless exchange over the mesh when some
+  shard's sketch lives in another process.
 
 Bin ids are ``uint8``, so ``n_bins`` is hard-capped at 256 — validated here
 and in ``ForestConfig`` with a typed ``BinCountError`` instead of silently

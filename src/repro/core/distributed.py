@@ -41,6 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .binning import (
+    DEFAULT_SKETCH_SIZE, StreamingQuantileSketch, validate_n_bins,
+)
 from .dsi import bootstrap_counts
 from .engine import (
     CollectivePlane, _gather_feature_bins, _safe_mean, finalize_forest, grow,
@@ -1226,89 +1229,40 @@ def _sketch_exchange(mesh: Mesh, axes: tuple):
     ))
 
 
-@host_span("bin.fit")
-def fit_bins_sharded(
-    x,
-    n_bins: int,
-    mesh: Mesh,
-    *,
-    sample_block: int,
-    sample_axes: Sequence[str] = ("data",),
-    max_size: Optional[int] = None,
-    exclude_masks=None,
-    runtime=None,
-) -> np.ndarray:
-    """Distributed bin-edge fitting: one quantile sketch per data shard,
-    exchanged through the collective plane, merged host-side.
+def _shard_sketch(blocks, block_ids, n_features, max_size, exclude_masks):
+    """One data shard's ``StreamingQuantileSketch`` over its blocks."""
+    sk = StreamingQuantileSketch(n_features, max_size=max_size)
+    for i in block_ids:
+        i = int(i)
+        if exclude_masks is None:
+            mask = None
+        elif isinstance(exclude_masks, dict):
+            mask = exclude_masks.get(i)
+        elif callable(exclude_masks):
+            mask = exclude_masks(i)
+        else:
+            mask = exclude_masks[i]
+        sk.update(np.asarray(blocks[i]), exclude=mask)
+    return sk
 
-    The block list (``sample_blocks`` views of the source — typically an
-    ``np.memmap``) is partitioned contiguously over the ``sample_axes``
-    shards; each shard folds only its own blocks into a
-    ``StreamingQuantileSketch``, so per-shard memory stays O(block) +
-    O(F * max_size) — in a multi-process mesh each host would feed its
-    local shard of the file. The per-feature summaries then cross the
-    mesh as raw float64 **bit patterns** (uint32 words) through one
-    ``all_gather`` over ``sample_axes`` — exact regardless of jax's x64
-    mode — and are merged in shard order on the host. The result is
-    deterministic, and while every summary is uncompressed it is bitwise
-    identical to single-host ``fit_bins_blocked`` over the same blocks
-    (and therefore to the resident ``fit_bins`` at that scale). Wire
-    cost: ``D * F * 2 * max_size * 16`` bytes on the gather.
 
-    ``exclude_masks`` (sequence, dict keyed by global block index, or a
-    callable ``exclude_masks(i) -> mask | None`` for masks a multi-host
-    caller recomputes lazily) carries the validator's imputed-cell
-    masks, exactly as in ``fit_bins_blocked``.
+@host_span("bin.exchange")
+def _exchange_sketches(local, mesh: Mesh, axes: tuple, runtime=None) -> list:
+    """Every shard's sketch, from this process's ``local`` ones, through
+    one ``all_gather`` over ``axes``.
 
-    With ``runtime`` (``launch.multiproc.MultiHostMesh``) each process
-    sketches only the block subsets of its own device shards — the
-    block partition over shards is identical to the single-process
-    call, so a memmap source pages in only the owning host's blocks —
-    and the per-feature counts/compression/dtype metadata ride the
-    payload's extra row so every host can reconstruct every shard's
-    state from the gather alone. The merged edges are bitwise identical
-    either way.
+    Summaries never exceed ``2 * max_size`` points (the sketch
+    recompresses past that), so every shard ships the same fixed-width
+    payload of float64 value and weight **bit patterns** (uint32 words:
+    exact whatever jax's x64 mode), plus one metadata row per feature,
+    ``[count_lo, count_hi, compressed, dtype_char]``, so every process
+    rebuilds every shard's state from the gather alone.
     """
-    from ..data.pipeline import stream_blocks
-    from .binning import (
-        DEFAULT_SKETCH_SIZE, StreamingQuantileSketch, validate_n_bins,
-    )
-
-    n_bins = validate_n_bins(n_bins)
-    if max_size is None:
-        max_size = DEFAULT_SKETCH_SIZE
-    blocks = stream_blocks(x, sample_block, what="fit_bins_sharded")
-    n_features = int(np.asarray(blocks[0]).shape[1])
-    axes = tuple(sample_axes)
-    n_shards = 1
-    for a in axes:
-        n_shards *= int(mesh.shape[a])
-    parts = np.array_split(np.arange(len(blocks)), n_shards)
-    mine = (
-        range(runtime.shard_lo, runtime.shard_hi) if runtime is not None
-        else range(n_shards)
-    )
-
-    # Summaries never exceed 2 * max_size points (the sketch recompresses
-    # past that), so every shard ships the same fixed-width payload. One
-    # extra metadata row per feature carries [count_lo, count_hi,
-    # compressed, dtype_char] so remote shards' states reconstruct from
-    # the gather alone.
+    n_features, max_size = local[0].n_features, local[0].max_size
+    n_shards = int(np.prod([mesh.shape[a] for a in axes]))
     width = 2 * max_size
-    payloads = np.zeros((len(mine), n_features, width + 1, 4), np.uint32)
-    for row, d in enumerate(mine):
-        sk = StreamingQuantileSketch(n_features, max_size=max_size)
-        for i in parts[d]:
-            i = int(i)
-            if exclude_masks is None:
-                mask = None
-            elif isinstance(exclude_masks, dict):
-                mask = exclude_masks.get(i)
-            elif callable(exclude_masks):
-                mask = exclude_masks(i)
-            else:
-                mask = exclude_masks[i]
-            sk.update(np.asarray(blocks[i]), exclude=mask)
+    payloads = np.zeros((len(local), n_features, width + 1, 4), np.uint32)
+    for row, sk in enumerate(local):
         st = sk.state(pad_to=width)
         packed = np.ascontiguousarray(
             np.stack([st["values"], st["weights"]], axis=-1)
@@ -1338,13 +1292,13 @@ def fit_bins_sharded(
     gathered = _sketch_exchange(mesh, axes)(p_dev)
     gathered = np.ascontiguousarray(np.asarray(jax.device_get(gathered)))
 
-    merged = None
+    sketches = []
     for d in range(n_shards):
         meta = gathered[d, :, width]
         unpacked = np.ascontiguousarray(gathered[d, :, :width]).view(
             np.float64
         ).reshape(n_features, width, 2)
-        st = {
+        sketches.append(StreamingQuantileSketch.from_state({
             "values": unpacked[..., 0],
             "weights": unpacked[..., 1],
             "count": (
@@ -1354,10 +1308,82 @@ def fit_bins_sharded(
             "compressed": meta[:, 2].astype(np.bool_),
             "value_dtype": np.dtype(chr(int(meta[0, 3]))).str,
             "max_size": max_size,
-        }
-        sk_d = StreamingQuantileSketch.from_state(st)
-        merged = sk_d if merged is None else merged.merge(sk_d)
+        }))
+    return sketches
+
+
+@host_span("bin.merge")
+def _merge_edges(sketches, n_bins: int) -> np.ndarray:
+    """Fold the shard sketches in shard order (into the first) and read
+    the edges."""
+    merged = sketches[0]
+    for sk in sketches[1:]:
+        merged = merged.merge(sk)
     return merged.edges(n_bins)
+
+
+@host_span("bin.fit")
+def fit_bins_sharded(
+    x,
+    n_bins: int,
+    mesh: Mesh,
+    *,
+    sample_block: int,
+    sample_axes: Sequence[str] = ("data",),
+    max_size: Optional[int] = None,
+    exclude_masks=None,
+    runtime=None,
+) -> np.ndarray:
+    """Distributed bin-edge fitting: one quantile sketch per data shard,
+    merged host-side in shard order.
+
+    The block list (``sample_blocks`` views of the source — typically an
+    ``np.memmap``) is partitioned contiguously over the ``sample_axes``
+    shards; each shard folds only its own blocks into a
+    ``StreamingQuantileSketch``, so per-shard memory stays O(block) +
+    O(F * max_size). The sketches are merged in shard order and the
+    result is deterministic; while every summary is uncompressed it is
+    bitwise identical to single-host ``fit_bins_blocked`` over the same
+    blocks (and therefore to the resident ``fit_bins`` at that scale).
+
+    A process that holds every shard's sketch (the single-process call)
+    merges them in memory and runs nothing on the devices. Only when
+    some shard's sketch lives in another process (``runtime``, a
+    ``launch.multiproc.MultiHostMesh``) do the summaries cross the mesh
+    (``_exchange_sketches``: one ``all_gather`` over ``sample_axes`` of
+    float64 bit patterns, ``D * F * 2 * max_size * 16`` bytes on the
+    wire); the round trip is lossless, so the merged edges are bitwise
+    identical either way. There each process sketches only the block
+    subsets of its own device shards — the block partition over shards
+    is identical to the single-process call, so a memmap source pages
+    in only the owning host's blocks.
+
+    ``exclude_masks`` (sequence, dict keyed by global block index, or a
+    callable ``exclude_masks(i) -> mask | None`` for masks a multi-host
+    caller recomputes lazily) carries the validator's imputed-cell
+    masks, exactly as in ``fit_bins_blocked``.
+    """
+    from ..data.pipeline import stream_blocks
+
+    n_bins = validate_n_bins(n_bins)
+    if max_size is None:
+        max_size = DEFAULT_SKETCH_SIZE
+    blocks = stream_blocks(x, sample_block, what="fit_bins_sharded")
+    n_features = int(np.asarray(blocks[0]).shape[1])
+    axes = tuple(sample_axes)
+    n_shards = int(np.prod([mesh.shape[a] for a in axes]))
+    parts = np.array_split(np.arange(len(blocks)), n_shards)
+    mine = (
+        range(runtime.shard_lo, runtime.shard_hi) if runtime is not None
+        else range(n_shards)
+    )
+    sketches = [
+        _shard_sketch(blocks, parts[d], n_features, max_size, exclude_masks)
+        for d in mine
+    ]
+    if len(mine) < n_shards:
+        sketches = _exchange_sketches(sketches, mesh, axes, runtime)
+    return _merge_edges(sketches, n_bins)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ validator exclusion masks, and the mesh exchange all reproduce the same
 edges. Plus the uint8 bin-count guard and the float32 edge-boundary
 contract of `apply_bins`.
 """
+import json
 import os
 import subprocess
 import sys
@@ -429,3 +430,104 @@ def test_fit_bins_sharded_matches_blocked_and_exact():
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "SHARDED_BINNING_OK" in out.stdout
+
+
+# One process holds every shard's sketch: the in-process merge against
+# the exchange (pack, gather, unpack) on the same shard sketches.
+SHARDED_CASES = {
+    "exact": dict(n_bins=32, sample_block=170),
+    # 250 rows per shard against 2 * 16 points: every summary recompresses.
+    "compressed": dict(n_bins=16, sample_block=50, max_size=16),
+    "fewer_blocks_than_shards": dict(n_bins=16, sample_block=400),
+    "exclusion_masks": dict(n_bins=16, sample_block=170, masks=(0, 3)),
+    "data_and_model": dict(n_bins=16, sample_block=100,
+                           sample_axes=("data", "model")),
+}
+
+SHARDED_SCRIPT = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import json
+    import jax
+    import numpy as np
+    from repro.core import distributed
+    from repro.core.binning import DEFAULT_SKETCH_SIZE
+    from repro.data.pipeline import sample_blocks
+    from repro.launch.mesh import make_mesh
+
+    cases, out = json.loads(sys.argv[1]), sys.argv[2]
+    rng = np.random.default_rng(5)
+    x = rng.standard_normal((1000, 6)).astype(np.float32)
+    mesh = make_mesh((4, 2), ("data", "model"))
+
+    def masks(case):
+        return {i: rng.random((case["sample_block"], 6)) < 0.05
+                for i in case.get("masks", ())}
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _d, **_kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    exchange = distributed._sketch_exchange
+
+    def no_device(*a, **k):
+        raise AssertionError("a single-process fit ran the sketch exchange")
+
+    distributed._sketch_exchange = no_device
+    res, ms = {}, {}
+    for name, case in cases.items():
+        ms[name] = masks(case)
+        res[name + ".fit"] = distributed.fit_bins_sharded(
+            x, case["n_bins"], mesh, sample_block=case["sample_block"],
+            sample_axes=tuple(case.get("sample_axes", ("data",))),
+            max_size=case.get("max_size"), exclude_masks=ms[name] or None)
+    res["compiles_in_process"] = np.array(len(compiles))
+
+    distributed._sketch_exchange = exchange
+    for name, case in cases.items():
+        axes = tuple(case.get("sample_axes", ("data",)))
+        blocks = sample_blocks(x, case["sample_block"])
+        n_shards = int(np.prod([mesh.shape[a] for a in axes]))
+        local = [
+            distributed._shard_sketch(
+                blocks, p, 6, case.get("max_size") or DEFAULT_SKETCH_SIZE,
+                ms[name] or None)
+            for p in np.array_split(np.arange(len(blocks)), n_shards)
+        ]
+        wire = distributed._exchange_sketches(local, mesh, axes)
+        res[name + ".compressed"] = np.array(not all(s.exact for s in wire))
+        res[name + ".empty_shard"] = np.array(
+            any(not s.count.any() for s in wire))
+        res[name + ".wire"] = distributed._merge_edges(wire, case["n_bins"])
+    np.savez(out, **res)
+    print("SHARDED_MERGE_OK")
+""")
+
+
+@pytest.fixture(scope="module")
+def sharded_merge(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("sharded") / "edges.npz")
+    out = subprocess.run(
+        [sys.executable, "-c", SHARDED_SCRIPT, json.dumps(SHARDED_CASES), path],
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "PYTHONPATH": "src"},
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "SHARDED_MERGE_OK" in out.stdout
+    return dict(np.load(path))
+
+
+@pytest.mark.parametrize("case", list(SHARDED_CASES))
+def test_in_process_merge_equals_the_exchange_bitwise(sharded_merge, case):
+    fit, wire = sharded_merge[case + ".fit"], sharded_merge[case + ".wire"]
+    assert fit.dtype == wire.dtype == np.float64
+    np.testing.assert_array_equal(fit, wire)
+    assert bool(sharded_merge[case + ".compressed"]) == (case == "compressed")
+    assert bool(sharded_merge[case + ".empty_shard"]) == (
+        case == "fewer_blocks_than_shards")
+
+
+def test_single_process_fit_runs_no_device_program(sharded_merge):
+    # Every in-process fit above ran with `_sketch_exchange` patched to
+    # raise, in a fresh process, before anything else touched the devices.
+    assert int(sharded_merge["compiles_in_process"]) == 0

@@ -11,7 +11,9 @@
   scopes, and every collective of its level loop runs under a
   ``prf.mesh.`` scope;
 * ``train_prf`` writes its eight host spans in call order, nested under
-  ``prf.train``, into a profiler trace.
+  ``prf.train``, into a profiler trace;
+* a single-process ``fit_bins_sharded`` merges its shard sketches under
+  ``prf.bin.merge`` inside ``prf.bin.fit``, with no ``prf.bin.exchange``.
 """
 import glob
 import os
@@ -220,3 +222,16 @@ def test_train_prf_host_spans_nest_in_call_order(tmp_path):
     _, t0, t1 = spans[0]
     assert all(t0 <= s <= e <= t1 for _, s, e in spans[1:])
     assert all(a[2] <= b[1] for a, b in zip(spans[1:], spans[2:]))
+
+
+def test_single_process_fit_bins_sharded_merges_without_the_exchange(tmp_path):
+    from repro.core.distributed import fit_bins_sharded
+
+    x = np.random.default_rng(2).normal(size=(N, F)).astype(np.float32)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    with jax.profiler.trace(str(tmp_path)):
+        fit_bins_sharded(x, B, mesh, sample_block=64)
+    spans = _host_spans(str(tmp_path))
+    assert [s[0] for s in spans] == ["prf.bin.fit", "prf.bin.merge"]
+    (_, t0, t1), (_, s, e) = spans
+    assert t0 <= s <= e <= t1
