@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.api import grow_forest_streamed
+from repro.core import grow_forest_streamed
 from repro.core.binning import bin_dataset
 from repro.core.dsi import bootstrap_counts
 from repro.core.forest import grow_forest
@@ -58,7 +58,7 @@ _MP_CODE = textwrap.dedent("""
     multiproc.initialize("127.0.0.1:" + os.environ["PRF_PORT"],
                          nproc, pid, local_device_count=2)
     import json, time
-    from repro.core.distributed import train_prf_multiproc
+    from repro.core.api import train_prf_multiproc
     from repro.core.types import ForestConfig
     from repro.data.tabular import make_classification
     from repro.launch.multiproc import MultiHostMesh

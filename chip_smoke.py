@@ -22,7 +22,7 @@ width the repo ships (``ForestConfig()``: 32 trees, depth 8, 64 bins,
               ``submit``/``drain`` labels equal to ``model.predict``.
 
 ``--chips 4`` runs only the vertically partitioned mesh path and what it
-is compared with: ``grow_forest_streamed_sharded`` on a (data=2,
+is compared with: ``grow_forest_streamed`` on a (data=2,
 model=2) mesh against ``grow_forest`` on one chip (bitwise), and
 ``make_sharded_vote_fn`` over the four chips against one-chip
 ``predict`` (labels equal).
@@ -279,7 +279,7 @@ def four_chips(seed: int, clock: PhaseClock) -> None:
     from repro.core import ForestConfig
     from repro.core.binning import apply_bins, bin_dataset
     from repro.core.dimred import dimension_reduction
-    from repro.core.distributed import grow_forest_streamed_sharded
+    from repro.core.distributed import grow_forest_streamed
     from repro.core.dsi import bootstrap_counts
     from repro.core.forest import grow_forest
     from repro.core.voting import predict
@@ -302,11 +302,11 @@ def four_chips(seed: int, clock: PhaseClock) -> None:
     mesh = make_mesh((2, 2), ("data", "model"))
     log(f"mesh: {dict(mesh.shape)} over {[d.id for d in mesh.devices.flat]}")
     f_mesh = clock.run(
-        "mesh grow_forest_streamed_sharded (data=2, model=2)",
-        lambda: grow_forest_streamed_sharded(
+        "mesh grow_forest_streamed (data=2, model=2)",
+        lambda: grow_forest_streamed(
             xb_np, y_tr, np.asarray(w),
-            dataclasses.replace(cfg, sample_block=SAMPLE_BLOCK), mesh,
-            feature_mask=np.asarray(mask),
+            dataclasses.replace(cfg, sample_block=SAMPLE_BLOCK),
+            np.asarray(mask), mesh=mesh,
         ),
     )
     diff = first_difference(f_mesh, f_one)

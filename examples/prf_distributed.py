@@ -61,7 +61,7 @@ def run_multiproc_worker(args):
         sample_block=args.rows // 4,
     )
     runtime = MultiHostMesh()
-    from repro.core.distributed import train_prf_multiproc
+    from repro.core.api import train_prf_multiproc
 
     model = train_prf_multiproc(x, y, cfg, seed=0, runtime=runtime)
     lo, hi = runtime.local_row_range(

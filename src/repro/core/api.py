@@ -3,17 +3,19 @@
     bin -> DSI bootstrap -> dimension reduction (Alg. 3.1)
         -> level-synchronous growth (Alg. 4.2) -> OOB weights (Eq. 8)
 
-``train_prf`` is the single-host path; ``repro.core.distributed`` offers
-the mesh-sharded version with identical semantics, and
-``grow_forest_streamed`` the host-streaming out-of-core growth driver
-(sample blocks fed from a NumPy/memmap source — the full ``[N, F]``
-matrix is never passed to one device call).
+``train_prf`` is the single-process pipeline: resident, or streamed
+through ``distributed.grow_forest_streamed`` when
+``config.sample_block > 0`` (sample blocks fed from a NumPy/memmap
+source — the full ``[N, F]`` matrix is never passed to one device
+call). ``train_prf_multiproc`` runs the same pipeline across
+``jax.distributed`` processes; ``repro.core.distributed`` holds the mesh
+kernels all three build on.
 """
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional, Sequence, Union
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -23,17 +25,14 @@ from .binning import apply_bins, fit_bins, fit_bins_blocked
 from .dimred import (
     dimension_reduction, dimension_reduction_streamed, random_feature_mask,
 )
-from .dsi import bootstrap_counts
-from .engine import (
-    LocalPlane, _safe_mean, finalize_forest, init_forest, init_hist_cache,
-    next_frontier, plan_level, resolve_hist_reuse, reuse_expand_scores,
-    stream_block_step, write_level,
+from .distributed import (
+    _dimred_streamed_multiproc, fit_bins_sharded, grow_forest_streamed,
+    oob_accuracy_streamed_sharded,
 )
+from .dsi import bootstrap_counts
 from .forest import (
     grow_forest, grow_forest_checkpointed, grown_leaves, with_leaves,
 )
-from .gain import SplitScores, level_scores, resolve_split_backend, sibling_plan
-from .histograms import class_channels, regression_channels
 from .tracing import host_span
 from .types import Forest, ForestConfig
 from .voting import (
@@ -197,8 +196,6 @@ def train_prf(
         # every process runs the same train_prf call collectively, each
         # feeding only its local rows. Bitwise identical to the
         # single-process planes.
-        from .distributed import train_prf_multiproc
-
         return train_prf_multiproc(
             x, y, config, seed,
             checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
@@ -323,14 +320,6 @@ def train_prf(
 # ---------------------------------------------------------------------------
 # Host-streaming out-of-core training (the streaming data plane)
 # ---------------------------------------------------------------------------
-
-
-def _channels(y: jnp.ndarray, config: ForestConfig) -> jnp.ndarray:
-    return (
-        regression_channels(y)
-        if config.regression
-        else class_channels(y, config.n_classes)
-    )
 
 
 def _train_prf_streamed(
@@ -509,320 +498,260 @@ def _train_prf_streamed(
     return PRFModel(forest=forest, bin_edges=edges, quarantine=report)
 
 
-@partial(jax.jit, static_argnames=("config",))
-def _stream_init(level0_hist, config):
-    """Root node from the accumulated level-0 histogram: at level 0
-    every sample sits in slot 0, so one feature's bin marginal IS the
-    [k, C] root class counts — no extra pass over the blocks."""
-    root_counts = level0_hist[:, 0, 0].sum(axis=1)
-    forest = init_forest(config)
-    forest = dataclasses.replace(
-        forest, class_counts=forest.class_counts.at[:, 0].set(root_counts)
-    )
-    if config.regression:
-        forest = dataclasses.replace(
-            forest, value=forest.value.at[:, 0].set(_safe_mean(root_counts))
-        )
-    return forest
-
-
-@partial(jax.jit, static_argnames=("config", "route"))
-def _stream_block_step(
-    hist_acc, xb_b, base_b, w_b, slot_b, slot_node, split_rank, scores,
-    config, route, small_right=None,
-):
-    """The fused route+histogram pass for one block on the local plane —
-    see ``engine.stream_block_step``. ONE jitted call, ONE read of the
-    block per level. ``small_right`` switches the block into the packed
-    sibling-subtraction histogram (``config.hist_reuse``)."""
-    return stream_block_step(
-        hist_acc, xb_b, base_b, w_b, slot_b, slot_node, split_rank, scores,
-        config, LocalPlane(), route=route, small_right=small_right,
-    )
-
-
-@partial(jax.jit, static_argnames=("config",))
-def _stream_plan_write(forest, slot_node, hist, feature_mask, level, config):
-    """T_NS + node writes for one level, from the accumulated histogram.
-    Runs the same plan/write/frontier pieces as the resident engine."""
-    scores, n_node = level_scores(
-        hist, feature_mask, regression=config.regression,
-        backend=resolve_split_backend(config.split_backend),
-    )
-    split_rank, is_split, child_base = plan_level(
-        scores, n_node, slot_node, config, level
-    )
-    forest = write_level(
-        forest, slot_node, split_rank, is_split, child_base, scores, config
-    )
-    new_slot_node = next_frontier(is_split, child_base, config.frontier)
-    return forest, scores, split_rank, new_slot_node
-
-
-@partial(jax.jit, static_argnames=("config",))
-def _stream_plan_write_reuse(
-    forest, slot_node, packed_h, cache, feature_mask, level, config,
-):
-    """Reuse-mode ``_stream_plan_write``: the level's accumulated packed
-    (small-child) histogram is expanded against the cache
-    (``parent - small``), scored in paired-row order, permuted back to
-    slots, and the refreshed cache — this level's paired tensor plus the
-    next level's small-side plan — rides out with the level plan."""
-    scores, n_node, hist2, perm = reuse_expand_scores(
-        packed_h, cache, feature_mask, config
-    )
-    split_rank, is_split, child_base = plan_level(
-        scores, n_node, slot_node, config, level
-    )
-    forest = write_level(
-        forest, slot_node, split_rank, is_split, child_base, scores, config
-    )
-    new_slot_node = next_frontier(is_split, child_base, config.frontier)
-    parent, small_right = sibling_plan(
-        scores, split_rank, is_split,
-        n_ranks=config.max_splits_per_level, regression=config.regression,
-    )
-    new_cache = {
-        "hist": hist2, "perm": perm,
-        "parent": parent, "small_right": small_right,
-    }
-    return forest, scores, split_rank, new_slot_node, new_cache
-
-
-def _stream_setup(
-    x_binned, y, weights, config: ForestConfig, prefetch: int,
-    feeder_opts: Optional[dict] = None,
-    quarantined: Sequence[int] = (),
-):
-    """Shared host-side setup of the streaming growth drivers: validated
-    block list and a ``BlockFeeder`` over the blocks. ``feeder_opts``
-    forwards retry/backoff/fault-injection/validator knobs to the
-    feeder; ``quarantined`` block indices are dropped from every sweep
-    (never transferred to a device)."""
-    from ..data.pipeline import BlockFeeder, stream_blocks
-
-    y_np = np.asarray(y)
-    w_np = np.asarray(weights, dtype=np.float32)
-    blocks = stream_blocks(
-        x_binned, config.sample_block, what="grow_forest_streamed",
-        n_y=y_np.shape[0], n_w=w_np.shape[1],
-    )
-    sizes = [b.shape[0] for b in blocks]
-    offsets = np.concatenate([[0], np.cumsum(sizes)])
-    if config.regression:
-        y_np = y_np.astype(np.float32)
-    feeder = BlockFeeder(
-        blocks, prefetch=prefetch, quarantined=quarantined,
-        **(feeder_opts or {}),
-    )
-    return feeder, y_np, w_np, sizes, offsets
-
-
-def _stream_state_like(sizes, config: ForestConfig, hist_width: int = 0):
-    """Structure template for the streamed growth checkpoint: the
-    host-driven driver's full inter-level carry. ``scores``/``split_rank``
-    must be part of it — the streaming plane fuses each level's routing
-    into the NEXT level's block sweep, so resuming at level L+1 needs
-    level L's plan, not just the forest and frontier.
-
-    ``hist_width > 0`` adds the sibling-subtraction cache (the plane's
-    post-combine feature width) — the reuse carry must be durable or a
-    resumed run would lose the subtraction baseline. With reuse off the
-    entry is ``None``, an *empty* pytree child, so off-mode templates
-    (and therefore existing checkpoints) are byte-compatible."""
-    k, S = config.n_trees, config.frontier
-    C = 3 if config.regression else config.n_classes
-    return {
-        "forest": init_forest(config),
-        "slot_node": jnp.zeros((k, S), jnp.int32),
-        "scores": SplitScores(
-            jnp.zeros((k, S), jnp.float32),
-            jnp.zeros((k, S), jnp.int32),
-            jnp.zeros((k, S), jnp.int32),
-            jnp.zeros((k, S, C), jnp.float32),
-            jnp.zeros((k, S, C), jnp.float32),
-        ),
-        "split_rank": jnp.zeros((k, S), jnp.int32),
-        "slots": [jnp.zeros((k, n), jnp.int32) for n in sizes],
-        "level": jnp.asarray(0, jnp.int32),
-        "hist_cache": (
-            init_hist_cache(config, hist_width) if hist_width > 0 else None
-        ),
-    }
-
-
-def grow_forest_streamed(
-    x_binned: Union[np.ndarray, Sequence[np.ndarray]],
-    y: np.ndarray,
-    weights: np.ndarray,
-    config: ForestConfig,
-    feature_mask: Optional[np.ndarray] = None,
-    *,
-    prefetch: int = 2,
-    manager=None,
+def train_prf_multiproc(
+    x, y, config: ForestConfig, seed: int = 0, *,
+    runtime=None,
+    checkpoint_dir: Optional[str] = None,
+    checkpoint_every: int = 1,
+    checkpoint_keep: int = 3,
     resume_from: Optional[str] = None,
     on_level=None,
     feeder_opts: Optional[dict] = None,
-    quarantined: Sequence[int] = (),
-) -> Forest:
-    """Out-of-core ``grow_forest`` over the async streaming data plane.
+    bad_block_policy: Optional[str] = "raise",
+    sample_axes: Sequence[str] = ("data",),
+    feature_axis: str = "model",
+    sketch_max_size: Optional[int] = None,
+):
+    """End-to-end ``train_prf`` across ``jax.distributed`` processes.
 
-    ``x_binned`` is either a host array / ``np.memmap`` of binned
-    features ``[N, F]`` (sliced into ``config.sample_block``-row views —
-    no copy; ``sample_block > 0`` is required so the full matrix can
-    never silently become one device block) or an explicit sequence of
-    ``[Nb, F]`` blocks.
+    The whole pipeline — integrity screen, bin-edge fitting, binning,
+    DSI bootstrap, dimension reduction, growth, OOB weighting — runs
+    with every process touching only the rows its sample-axis shards
+    own (``x`` is typically an ``np.memmap``; remote rows are never
+    paged in, except that edge fitting reads the full blocks of this
+    process's shard *subset* — the same block partition as
+    ``fit_bins_sharded``). The trained model is **bitwise identical**
+    to the single-process ``train_prf`` on the same ``(x, y, config,
+    seed)``:
 
-    Data-plane accounting (each device call only ever sees one block):
+    * the per-block validator scans local rows and union-reduces the
+      per-(block, column) bad-cell counts through one exact integer
+      ``psum_hosts``, so every process reaches the same verdict (and
+      the same typed ``DataIntegrityError`` under ``"raise"``); label
+      screening runs on the globally-resident ``y`` identically
+      everywhere;
+    * edges come from per-shard quantile sketches merged bit-exactly;
+    * the bootstrap/feature-mask PRNG draws are process-independent
+      functions of ``seed``;
+    * growth/dimred/OOB accumulate exact integer-valued f32 sums, so
+      shard-order never matters.
 
-    * **one read per level** — per block per level, ONE jitted call
-      (``engine.stream_block_step``) routes the block's samples from
-      the previous level's frontier and immediately folds them into
-      this level's histogram carry, so the route and histogram passes
-      share a single host->device feed of the block;
-    * **async double-buffering** — a ``BlockFeeder`` thread keeps
-      ``prefetch`` block copies in flight, so block ``i+1``'s
-      host->device transfer overlaps block ``i``'s histogram
-      (``prefetch=0`` restores the synchronous feed);
-    * **pinned per-block constants** — label channels and DSI weights
-      are uploaded once for the whole growth, not once per level, and
-      the per-sample slot table stays device-resident across levels
-      (no host round-trip per block per level).
-
-    Per level, one jitted call then scores + writes the level with the
-    engine's shared ``plan_level`` / ``write_level`` / ``next_frontier``
-    pieces. Root class counts come for free from the level-0 histogram
-    (every sample sits in slot 0). Device memory: the ``[N, F]`` bin
-    matrix — the dominant term for realistic F — is never resident
-    (one ``sample_block * F`` block at a time, plus the
-    ``k*S*F*B*C`` histogram carry), but the pinned weight/channel/slot
-    operands DO scale with N: ``(2k + C) * N`` f32/int32 words stay on
-    device for the whole growth (the price of feeding them zero times
-    per level instead of twice). With k trees per host ≪ F features
-    that is a small fraction of the streamed data; for very large
-    ensembles, shard trees across hosts before streaming.
-
-    DSI counts are integer-valued, so the blocked accumulation is
-    bit-exact for classification: the result equals the resident
-    ``grow_forest`` forest array for array (tests/test_engine.py pins
-    this across >= 4 blocks, with and without prefetch). Regression
-    channels agree to float rounding. Host-side early exit stops the
-    level loop as soon as every tree's frontier is empty (always on —
-    the loop is host-driven and the forests are identical either way;
-    ``config.early_exit`` only gates the device-side ``lax.while_loop``).
-
-    **Checkpointing** mirrors ``grow_forest_checkpointed``: ``manager``
-    saves the driver's full inter-level carry (forest, frontier, level
-    plan, per-block slot tables — see ``_stream_state_like``) after
-    each level; ``resume_from`` restores the newest *CRC-verified*
-    carry (``checkpoint.restore_latest_valid`` — a corrupted or torn
-    newest step is skipped, costing recompute of the affected levels,
-    never a poisoned model) and the level loop continues where it
-    stopped, producing the bit-identical forest.
-    ``on_level(level, forest)`` fires after each completed level's
-    checkpoint.
-
-    **Quarantine.** ``quarantined`` block indices (plus any the feeder's
-    own ``validator`` flags — forward one via ``feeder_opts``) are
-    dropped from every level sweep: never transferred, never routed,
-    never histogrammed. Their slot-table entries stay as zeros in the
-    checkpoint carry, so the carry structure — and therefore resume —
-    is independent of which blocks were quarantined.
+    ``checkpoint_dir``/``resume_from`` go through the multi-process
+    checkpoint protocol (process-0 manifest, per-host shard leaves);
+    resuming under a different process count raises
+    ``CheckpointTopologyError``. Regression with ``weighted_voting``
+    is not wired on this plane yet and raises ``NotImplementedError``.
+    ``sketch_max_size`` caps the per-shard quantile summary (wire and
+    host cost of edge fitting scale with it; below the compression
+    threshold edges are exact).
     """
-    feeder, y_np, w_np, sizes, offsets = _stream_setup(
-        x_binned, y, weights, config, prefetch, feeder_opts, quarantined
+    from ..data.pipeline import (
+        BlockIssue, BlockValidator, DataIntegrityError, QuarantineReport,
+    )
+    from ..launch.multiproc import MultiHostMesh, MultiprocCheckpointManager
+
+    config = config.resolved(x.shape[1])
+    if config.sample_block <= 0:
+        raise ValueError(
+            "train_prf_multiproc needs config.sample_block > 0 — the "
+            "multi-process plane is streaming-only (each process feeds "
+            "its local rows of every sample block)"
+        )
+    if getattr(x, "ndim", None) != 2:
+        raise ValueError(
+            "train_prf_multiproc needs a 2-D [N, F] array-like source "
+            "(np.memmap / np.ndarray) so every process can slice its own "
+            f"rows; got {type(x).__name__}"
+        )
+    if runtime is None:
+        runtime = MultiHostMesh(
+            sample_axes=sample_axes, feature_axis=feature_axis
+        )
+    mesh = runtime.mesh
+    sample_axes = tuple(sample_axes)
+    D = runtime.n_data_shards
+    N, F = int(x.shape[0]), int(x.shape[1])
+    nb = config.sample_block
+    sizes = [min(nb, N - o) for o in range(0, N, nb)]
+    offsets = np.concatenate([[0], np.cumsum(sizes)])
+    n_blocks = len(sizes)
+    ms = [n + ((-n) % D) for n in sizes]
+    windows = [runtime.local_row_range(m) for m in ms]
+    y_host = np.asarray(y)
+
+    def _local_view(i):
+        """(view of x's local real rows of block i, their count)."""
+        lo, hi = windows[i]
+        o0 = offsets[i]
+        nreal = max(min(hi, sizes[i]) - lo, 0)
+        return x[o0 + lo:o0 + lo + nreal], nreal
+
+    # ---- integrity screen (union-reduced across processes) ------------
+    report = None
+    cell_cols = None
+    label_masks = {}
+    quar = frozenset()
+    if bad_block_policy not in (None, "off"):
+        validator = BlockValidator(
+            bad_block_policy, n_features=F,
+            n_classes=None if config.regression else config.n_classes,
+            regression=config.regression,
+        )
+        counts = np.zeros((n_blocks, F), np.int64)
+        if np.issubdtype(np.asarray(x[:0]).dtype, np.inexact):
+            for i in range(n_blocks):
+                view, nreal = _local_view(i)
+                if nreal:
+                    counts[i] = (~np.isfinite(np.asarray(view))).sum(axis=0)
+        cell_cols = runtime.psum_hosts(counts.ravel()).reshape(n_blocks, F)
+        for i in range(n_blocks):
+            lm = validator._label_mask(y_host[offsets[i]:offsets[i + 1]])
+            if lm.any():
+                label_masks[i] = lm
+        report = QuarantineReport(
+            policy=bad_block_policy, blocks_checked=n_blocks,
+        )
+        for i in range(n_blocks):
+            bad_cells = int(cell_cols[i].sum())
+            bad_labels = int(label_masks[i].sum()) if i in label_masks else 0
+            if not bad_cells and not bad_labels:
+                continue
+            issue = BlockIssue(
+                index=i, reason="nonfinite" if bad_cells else "label",
+                columns=tuple(int(c) for c in np.flatnonzero(cell_cols[i])),
+                bad_cells=bad_cells, bad_labels=bad_labels,
+            )
+            report.issues.append(issue)
+            if bad_block_policy == "raise":
+                raise DataIntegrityError(
+                    issue.describe(), block_index=i,
+                    columns=issue.columns, reason=issue.reason,
+                )
+            report.sanitized_cells += bad_cells
+            report.sanitized_labels += bad_labels
+            if bad_block_policy == "quarantine":
+                report.quarantined.append(i)
+        quar = frozenset(report.quarantined)
+        if len(quar) == n_blocks:
+            raise DataIntegrityError(
+                f"every block quarantined ({n_blocks} of {n_blocks}) — "
+                "nothing left to train on",
+                reason="quarantine",
+            )
+        if label_masks:
+            y_host = y_host.copy()
+            for i, lm in label_masks.items():
+                y_host[offsets[i]:offsets[i + 1]][lm] = 0
+    good = [i for i in range(n_blocks) if i not in quar]
+    flagged = (
+        set() if cell_cols is None
+        else {i for i in range(n_blocks) if cell_cols[i].any()}
     )
 
-    k, S = config.n_trees, config.frontier
-    F = feeder.blocks[0].shape[1]
-    B = config.n_bins
-    C = 3 if config.regression else config.n_classes
-    mask_dev = None if feature_mask is None else jnp.asarray(feature_mask)
-    # Sibling-subtraction reuse: blocks scatter into R rank segments
-    # instead of S slots (the per-level carry is half the tensor) and
-    # the plan step subtracts large children from the durable cache.
-    reuse = resolve_hist_reuse(config, F)
-    n_rows = config.max_splits_per_level if reuse else S
+    # ---- bin edges (per-shard sketches over the good blocks) ----------
+    good_views = [x[offsets[i]:offsets[i + 1]] for i in good]
 
-    # Per-block constants: pinned on device ONCE for the whole growth.
-    # Quarantined blocks get no pins — nothing of theirs ever lands on
-    # a device.
-    live = set(feeder.live_blocks)
-    base_dev, w_dev = [], []
-    for i in range(len(feeder)):
-        if i not in live:
-            base_dev.append(None)
-            w_dev.append(None)
+    def _exclude(j):
+        # Lazily recompute the imputed-cell mask of the j-th good block —
+        # only the sketching shard ever pages the full block in.
+        i = good[j]
+        if i not in flagged:
+            return None
+        return ~np.isfinite(np.asarray(good_views[j]))
+
+    edges = fit_bins_sharded(
+        good_views, config.n_bins, mesh,
+        sample_block=nb, sample_axes=sample_axes,
+        max_size=sketch_max_size,
+        exclude_masks=_exclude if flagged else None,
+        runtime=runtime,
+    )
+    edges_dev = jnp.asarray(edges)
+
+    # ---- bin the local rows of every block ----------------------------
+    xb_local = []
+    for i in range(n_blocks):
+        lo, hi = windows[i]
+        xbl = np.zeros((hi - lo, F), np.uint8)
+        if i in quar:
+            xb_local.append(xbl)             # placeholder, never swept
             continue
-        o0, o1 = offsets[i], offsets[i + 1]
-        base_dev.append(_channels(feeder.pin(y_np[o0:o1]), config))
-        w_dev.append(feeder.pin(w_np[:, o0:o1]))
+        view, nreal = _local_view(i)
+        if nreal:
+            xb = np.array(apply_bins(jnp.asarray(np.asarray(view)),
+                                     edges_dev))
+            if i in flagged:
+                # apply_bins is element-wise, so binning the local row
+                # slice matches the full-block binning bitwise; imputed
+                # cells are forced to bin 0 exactly like the single-host
+                # trainer.
+                xb[~np.isfinite(np.asarray(view))] = 0
+            xbl[:nreal] = xb
+        xb_local.append(xbl)
 
-    state = None
-    if resume_from is not None:
-        from ..checkpoint.checkpoint import restore_latest_valid
+    # ---- DSI bootstrap + feature selection (same PRNG everywhere) -----
+    key = jax.random.PRNGKey(seed)
+    k_boot, k_dim = jax.random.split(key)
+    w_np = np.asarray(bootstrap_counts(k_boot, config.n_trees, N))
+    if label_masks:
+        bad_rows = np.zeros(N, dtype=bool)
+        for i, lm in label_masks.items():
+            bad_rows[offsets[i]:offsets[i + 1]][lm] = True
+        w_np = np.where(bad_rows[None, :], 0, w_np)
 
-        restored = restore_latest_valid(
-            _stream_state_like(sizes, config, F if reuse else 0), resume_from
+    feature_mask = None
+    if config.feature_mode == "importance" and not config.regression:
+        feature_mask = _dimred_streamed_multiproc(
+            xb_local, y_host, w_np, config, k_dim, runtime,
+            sizes=sizes, quarantined=sorted(quar), feeder_opts=feeder_opts,
+            sample_axes=sample_axes, feature_axis=feature_axis,
         )
-        if restored is not None:
-            state, _ = restored
-    if state is not None:
-        forest, slot_node = state["forest"], state["slot_node"]
-        scores, split_rank = state["scores"], state["split_rank"]
-        slot_dev, start = list(state["slots"]), int(state["level"])
-        cache = state["hist_cache"]
-    else:
-        # The per-sample frontier table: device-resident across levels.
-        slot_dev = [jnp.zeros((k, n), jnp.int32) for n in sizes]
-        slot_node = jnp.full((k, S), -1, jnp.int32).at[:, 0].set(0)
-        forest, scores, split_rank = None, None, None
-        cache = init_hist_cache(config, F) if reuse else None
-        start = 0
+    elif config.feature_mode == "random":
+        feature_mask = np.asarray(random_feature_mask(
+            k_dim, n_trees=config.n_trees, n_features=F,
+            n_selected=config.n_selected,
+        ))
 
-    def level_sweep(route: bool):
-        hist = jnp.zeros((k, n_rows, F, B, C), jnp.float32)
-        for i, xb_b in zip(feeder.live_blocks, feeder.sweep()):
-            hist, slot_dev[i] = _stream_block_step(
-                hist, xb_b, base_dev[i], w_dev[i], slot_dev[i], slot_node,
-                split_rank if route else None, scores if route else None,
-                config, route,
-                cache["small_right"] if reuse else None,
+    # ---- growth (the runtime-threaded mesh streamed driver) -----------
+    manager = None
+    if checkpoint_dir is not None:
+        manager = MultiprocCheckpointManager(
+            checkpoint_dir, keep=checkpoint_keep,
+            save_interval=checkpoint_every, runtime=runtime,
+        )
+    y_grow = y_host if not config.regression else y_host.astype(np.float32)
+    forest = grow_forest_streamed(
+        xb_local, y_grow, w_np, config, feature_mask, mesh=mesh,
+        sample_axes=sample_axes, feature_axis=feature_axis,
+        manager=manager, resume_from=resume_from, on_level=on_level,
+        feeder_opts=feeder_opts, quarantined=sorted(quar),
+        runtime=runtime, block_sizes=sizes,
+    )
+
+    if config.weighted_voting:
+        if config.regression:
+            raise NotImplementedError(
+                "weighted_voting for regression (OOB R^2) is not wired "
+                "on the multi-process plane yet — set "
+                "weighted_voting=False, or train single-process"
             )
-        return hist
+        invalid = {}
+        for i, lm in label_masks.items():
+            if i in quar:
+                continue
+            lo, hi = windows[i]
+            nreal = max(min(hi, sizes[i]) - lo, 0)
+            m = np.zeros(hi - lo, bool)
+            m[:nreal] = lm[lo:lo + nreal]
+            if m.any():
+                invalid[i] = m
+        w = oob_accuracy_streamed_sharded(
+            forest, xb_local, y_host, w_np, mesh,
+            sample_axes=sample_axes, feature_axis=feature_axis,
+            feeder_opts=feeder_opts, quarantined=sorted(quar),
+            runtime=runtime, block_sizes=sizes,
+            invalid_masks=invalid or None,
+        )
+        forest = dataclasses.replace(forest, tree_weight=w)
 
-    try:
-        for level in range(start, config.max_depth):
-            if not np.any(np.asarray(slot_node) >= 0):
-                break                               # every frontier is empty
-            hist = level_sweep(route=level > 0)
-            if forest is None:
-                forest = _stream_init(hist, config)  # root node, free at level 0
-            if reuse:
-                forest, scores, split_rank, slot_node, cache = (
-                    _stream_plan_write_reuse(
-                        forest, slot_node, hist, cache, mask_dev,
-                        jnp.asarray(level, jnp.int32), config,
-                    )
-                )
-            else:
-                forest, scores, split_rank, slot_node = _stream_plan_write(
-                    forest, slot_node, hist, mask_dev,
-                    jnp.asarray(level, jnp.int32), config,
-                )
-            if manager is not None:
-                manager.maybe_save({
-                    "forest": forest, "slot_node": slot_node,
-                    "scores": scores, "split_rank": split_rank,
-                    "slots": slot_dev,
-                    "level": jnp.asarray(level + 1, jnp.int32),
-                    "hist_cache": cache,
-                }, level + 1)
-            if on_level is not None:
-                on_level(level + 1, forest)
-
-        if forest is None:          # max_depth == 0: root node only
-            forest = _stream_init(level_sweep(route=False), config)
-    finally:
-        feeder.close()
-    return finalize_forest(forest)
+    return PRFModel(forest=forest, bin_edges=edges, quarantine=report)

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import lru_cache, partial
+from types import SimpleNamespace
 from typing import Optional, Sequence
 
 import jax
@@ -41,6 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..checkpoint.checkpoint import restore_latest_valid
+from ..data.pipeline import BlockFeeder, stream_blocks
 from .binning import (
     DEFAULT_SKETCH_SIZE, StreamingQuantileSketch, validate_n_bins,
 )
@@ -51,14 +54,13 @@ from .engine import (
     next_frontier, plan_level, resolve_hist_reuse, reuse_expand_scores,
     stream_block_step, write_level,
 )
-from .types import GrowthState
 from .gain import (
     SplitScores, level_scores, multiway_gain_ratio, resolve_split_backend,
     sibling_plan,
 )
-from .histograms import class_channels, level_histograms, regression_channels
+from .histograms import class_channels, label_channels, level_histograms
 from .tracing import host_span, scope
-from .types import Forest, ForestConfig
+from .types import Forest, ForestConfig, GrowthState
 
 
 def _multi_axis_index(axes: Sequence[str]) -> jnp.ndarray:
@@ -212,13 +214,15 @@ def _grow_sharded(
 
 
 # ---------------------------------------------------------------------------
-# Mesh x streaming: host sample blocks fed into the collective plane
+# Host-driven resident mesh growth (per-level checkpointing)
 # ---------------------------------------------------------------------------
 
 
 def _pad_rows(a: np.ndarray, pad: int, fill=0):
+    """``a`` with ``pad`` rows of ``fill`` appended — ``a`` itself when
+    there is nothing to pad (a memmap view is never copied here)."""
     if pad == 0:
-        return np.ascontiguousarray(a)
+        return a
     width = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
     return np.pad(a, width, constant_values=fill)
 
@@ -251,8 +255,6 @@ def grow_sharded_checkpointed(
     zero-weight samples, invisible to histograms and root counts.
     """
     sample_axes = tuple(sample_axes)
-    from .api import _channels
-
     x_np = np.asarray(x_binned)
     y_np = np.asarray(y)
     w_np = np.asarray(weights, np.float32)
@@ -265,7 +267,9 @@ def grow_sharded_checkpointed(
     kn_sh = NamedSharding(mesh, P(None, sample_axes))
 
     xb = jax.device_put(_pad_rows(x_np, pad), x_sh)
-    base_dev = _channels(jax.device_put(_pad_rows(y_np, pad), row_sh), config)
+    base_dev = label_channels(
+        jax.device_put(_pad_rows(y_np, pad), row_sh), config
+    )
     w_dev = jax.device_put(_pad_rows(w_np.T, pad).T, kn_sh)
     mask_np = (
         np.ones((k, F), bool) if feature_mask is None
@@ -340,8 +344,6 @@ def grow_sharded_checkpointed(
 
     state = init_fn(base_dev, w_dev, mask_dev)
     if resume_from is not None:
-        from ..checkpoint.checkpoint import restore_latest_valid
-
         shardings = jax.tree_util.tree_map(lambda a: a.sharding, state)
         restored = restore_latest_valid(
             state, resume_from, shardings
@@ -366,16 +368,216 @@ def grow_sharded_checkpointed(
     return finalize_forest(forest)
 
 
+# ---------------------------------------------------------------------------
+# The streamed growth driver: host sample blocks fed into the collective plane
+# ---------------------------------------------------------------------------
 
 
-def grow_forest_streamed_sharded(
+def _stream_setup(
+    x_binned, y, weights, config: ForestConfig, *, runtime=None,
+    block_sizes: Optional[Sequence[int]] = None,
+):
+    """Host-side setup of the streamed driver: the validated block list
+    (``runtime``: this process's padded row slices of every block), the
+    labels (float32 for regression), the f32 DSI weights, and the global
+    unpadded block sizes with their row offsets."""
+    y_np = np.asarray(y)
+    if config.regression:
+        y_np = y_np.astype(np.float32)
+    w_np = np.asarray(weights, dtype=np.float32)
+    if runtime is not None:
+        if block_sizes is None:
+            raise ValueError(
+                "grow_forest_streamed(runtime=...) needs block_sizes — the "
+                "global unpadded sizes the host-local block slices were cut "
+                "from"
+            )
+        blocks, sizes = list(x_binned), [int(n) for n in block_sizes]
+    else:
+        blocks = stream_blocks(
+            x_binned, config.sample_block, what="grow_forest_streamed",
+            n_y=y_np.shape[0], n_w=w_np.shape[1],
+        )
+        sizes = [b.shape[0] for b in blocks]
+    offsets = np.concatenate([[0], np.cumsum(sizes)])
+    return blocks, y_np, w_np, sizes, offsets
+
+
+def _stream_state_like(sizes, config: ForestConfig, hist_width: int = 0):
+    """Structure template for the streamed growth checkpoint: the
+    host-driven driver's full inter-level carry. ``scores``/``split_rank``
+    must be part of it — the streaming plane fuses each level's routing
+    into the NEXT level's block sweep, so resuming at level L+1 needs
+    level L's plan, not just the forest and frontier.
+
+    ``hist_width > 0`` adds the sibling-subtraction cache (the global
+    feature width; the mesh shards it on restore) — the reuse carry must
+    be durable or a resumed run would lose the subtraction baseline.
+    With reuse off the entry is ``None``, an *empty* pytree child, so
+    off-mode templates (and therefore existing checkpoints) are
+    byte-compatible."""
+    k, S = config.n_trees, config.frontier
+    C = 3 if config.regression else config.n_classes
+    return {
+        "forest": init_forest(config),
+        "slot_node": jnp.zeros((k, S), jnp.int32),
+        "scores": SplitScores(
+            jnp.zeros((k, S), jnp.float32),
+            jnp.zeros((k, S), jnp.int32),
+            jnp.zeros((k, S), jnp.int32),
+            jnp.zeros((k, S, C), jnp.float32),
+            jnp.zeros((k, S, C), jnp.float32),
+        ),
+        "split_rank": jnp.zeros((k, S), jnp.int32),
+        "slots": [jnp.zeros((k, n), jnp.int32) for n in sizes],
+        "level": jnp.asarray(0, jnp.int32),
+        "hist_cache": (
+            init_hist_cache(config, hist_width) if hist_width > 0 else None
+        ),
+    }
+
+
+@lru_cache(maxsize=32)
+def _stream_programs(
+    mesh: Mesh, config: ForestConfig, sample_axes: tuple, feature_axis: str,
+    n_features: int,
+):
+    """The streamed driver's jitted ``shard_map`` programs for one (mesh,
+    config, feature width), built once: a later growth of the same shape
+    traces and compiles nothing again (the cache is bounded, since each
+    entry holds its compiled programs).
+
+    * ``step`` — one (block, level) call of ``engine.stream_block_step``
+      on every shard; it routes only when given the previous level's
+      plan (``split_rank`` not ``None``).
+    * ``plan`` — combine the level's per-shard partials, score, merge
+      the winners, write the level; given ``forest=None`` (level 0) it
+      first builds the root node from the combined histogram.
+    * ``root`` — the root node alone (``max_depth == 0``).
+
+    ``reuse`` (``config.hist_reuse``) makes the per-block partials the
+    packed ``R``-row rank segments and the plan step expand them against
+    the sibling cache, whose histogram is feature-sharded like the
+    checkpointed resident path's (``cache_specs``).
+    """
+    D = int(np.prod([mesh.shape[a] for a in sample_axes]))
+    Fl = n_features // int(mesh.shape[feature_axis])
+    use_rs = (
+        config.hist_reduce == "psum_scatter"
+        and len(sample_axes) == 1 and Fl % D == 0
+    )
+    reuse = resolve_hist_reuse(config, Fl)
+    hist_spec = P(sample_axes, None, None, feature_axis)
+    cache_specs = P()
+    if reuse:
+        hist_axes = (feature_axis, sample_axes[0]) if use_rs else feature_axis
+        cache_specs = {
+            "hist": P(None, None, hist_axes),
+            "perm": P(), "parent": P(), "small_right": P(),
+        }
+    split_be = resolve_split_backend(config.split_backend)
+
+    def make_plane(Fl, mask_loc=None):
+        return MeshPlane(
+            config, Fl, mask_loc,
+            sample_axes=sample_axes, feature_axis=feature_axis,
+        )
+
+    def root(hist_c):
+        # The one root-node builder of the driver. Root counts: any
+        # feature's bin marginal of the level-0 histogram (slot/rank row
+        # 0) sums to the [k, C] root class counts (identical on every
+        # shard — exact integer sums).
+        counts = hist_c[:, 0, 0].sum(axis=1)
+        forest = init_forest(config)
+        forest = dataclasses.replace(
+            forest, class_counts=forest.class_counts.at[:, 0].set(counts)
+        )
+        if config.regression:
+            forest = dataclasses.replace(
+                forest, value=forest.value.at[:, 0].set(_safe_mean(counts))
+            )
+        return forest
+
+    def step_kernel(hist_part, xb_loc, base_loc, w_loc, slot_loc, slot_node,
+                    split_rank, scores, small_right):
+        h, slot_loc = stream_block_step(
+            hist_part[0], xb_loc, base_loc, w_loc, slot_loc, slot_node,
+            split_rank, scores, config, make_plane(xb_loc.shape[1]),
+            route=split_rank is not None, small_right=small_right,
+        )
+        return h[None], slot_loc
+
+    def plan_kernel(hist_part, forest, slot_node, level, mask_loc, cache):
+        plane = make_plane(hist_part.shape[3], mask_loc)
+        hist_c = plane.combine_hist(hist_part[0])   # reuse: packed, half the wire
+        if forest is None:
+            forest = root(hist_c)
+        if reuse:
+            scores, n_node, hist2, perm = reuse_expand_scores(
+                hist_c, cache, plane.level_mask, config
+            )
+        else:
+            scores, n_node = level_scores(
+                hist_c, plane.level_mask, regression=config.regression,
+                backend=split_be,
+            )
+        scores, n_node = plane.merge_winners(scores, n_node)
+        split_rank, is_split, child_base = plan_level(
+            scores, n_node, slot_node, config, level
+        )
+        forest = write_level(
+            forest, slot_node, split_rank, is_split, child_base, scores,
+            config,
+        )
+        if reuse:
+            parent, small_right = sibling_plan(
+                scores, split_rank, is_split,
+                n_ranks=config.max_splits_per_level,
+                regression=config.regression,
+            )
+            cache = {"hist": hist2, "perm": perm,
+                     "parent": parent, "small_right": small_right}
+        return (
+            forest, scores, split_rank,
+            next_frontier(is_split, child_base, config.frontier), cache,
+        )
+
+    def root_kernel(hist_part):
+        return root(make_plane(hist_part.shape[3]).combine_hist(hist_part[0]))
+
+    def program(kernel, in_specs, out_specs):
+        return jax.jit(jax.shard_map(
+            kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+        ))
+
+    return SimpleNamespace(
+        reuse=reuse, hist_spec=hist_spec, cache_specs=cache_specs,
+        n_rows=config.max_splits_per_level if reuse else config.frontier,
+        step=program(
+            step_kernel,
+            (hist_spec, P(sample_axes, feature_axis), P(sample_axes),
+             P(None, sample_axes), P(None, sample_axes), P(), P(), P(), P()),
+            (hist_spec, P(None, sample_axes)),
+        ),
+        plan=program(
+            plan_kernel,
+            (hist_spec, P(), P(), P(), P(None, feature_axis), cache_specs),
+            (P(), P(), P(), P(), cache_specs),
+        ),
+        root=program(root_kernel, (hist_spec,), P()),
+    )
+
+
+def grow_forest_streamed(
     x_binned,
     y: np.ndarray,
     weights: np.ndarray,
     config: ForestConfig,
-    mesh: Mesh,
     feature_mask: Optional[np.ndarray] = None,
     *,
+    mesh: Optional[Mesh] = None,
     sample_axes: Sequence[str] = ("data",),
     feature_axis: str = "model",
     prefetch: int = 2,
@@ -387,37 +589,76 @@ def grow_forest_streamed_sharded(
     runtime=None,
     block_sizes: Optional[Sequence[int]] = None,
 ) -> Forest:
-    """Out-of-core growth on the **mesh** plane — the streaming data
-    plane composed with ``MeshPlane``'s collectives, lifting the
-    per-host memory cap on the distributed path too.
+    """Out-of-core ``grow_forest`` over the async streaming data plane,
+    on one device or a (sample x feature) mesh.
 
-    Per (block, level), ONE jitted ``shard_map`` call runs
-    ``engine.stream_block_step`` on every device: each shard routes its
-    (sample x feature) slice of the block (the winning feature's
-    go-right bit broadcast by ``MeshPlane.broadcast_route``'s masked
-    psum) and folds it into its **local** histogram partial — the
-    ``combine_hist`` collective (psum or psum_scatter, per
-    ``config.hist_reduce``) runs once per level in the plan step, not
-    once per block, so streaming adds zero extra collective traffic.
-    The per-shard partials live in a ``[D, k, S, F, B, C]`` carry
-    sharded ``P(sample_axes, ..., feature_axis)`` (each data shard owns
-    its row), and the per-sample slot table stays device-resident
-    sharded ``P(None, sample_axes)``.
+    ``x_binned`` is either a host array / ``np.memmap`` of binned
+    features ``[N, F]`` (sliced into ``config.sample_block``-row views —
+    no copy; ``sample_block > 0`` is required so the full matrix can
+    never silently become one device block) or an explicit sequence of
+    ``[Nb, F]`` blocks. ``mesh=None`` runs on a one-device mesh of the
+    default device, whose collectives are size-1 no-ops.
+
+    Data-plane accounting (each device call only ever sees one block):
+
+    * **one read per level** — per block per level, ONE jitted
+      ``shard_map`` call (``engine.stream_block_step``) routes each
+      shard's (sample x feature) slice of the block from the previous
+      level's frontier (the winning feature's go-right bit broadcast by
+      ``MeshPlane.broadcast_route``'s masked psum) and folds it into
+      the shard's **local** histogram partial. The ``combine_hist``
+      collective (psum or psum_scatter, per ``config.hist_reduce``) runs
+      once per level in the plan step, not once per block, so streaming
+      adds zero extra collective traffic. The partials live in a
+      ``[D, k, S, F, B, C]`` carry sharded ``P(sample_axes, ...,
+      feature_axis)``.
+    * **async double-buffering** — a ``BlockFeeder`` thread keeps
+      ``prefetch`` block copies in flight, so block ``i+1``'s
+      host->device transfer overlaps block ``i``'s histogram
+      (``prefetch=0`` restores the synchronous feed).
+    * **pinned per-block constants** — label channels and DSI weights
+      are pinned once for the whole growth through the feeder's bounded
+      retry, not fed once per level, and the per-sample slot table stays
+      device-resident across levels, sharded ``P(None, sample_axes)``.
+
+    Per level, one jitted call then scores + writes the level with the
+    engine's shared ``plan_level`` / ``write_level`` / ``next_frontier``
+    pieces. Root class counts come for free from the level-0 histogram
+    (every sample sits in slot 0). Device memory: the ``[N, F]`` bin
+    matrix — the dominant term for realistic F — is never resident,
+    but the pinned weight/channel/slot operands DO scale with N:
+    ``(2k + C) * N`` f32/int32 words stay on device for the whole
+    growth (the price of feeding them zero times per level instead of
+    twice).
 
     Blocks are padded host-side to a multiple of the data-axis size
     with parked samples (``slot = -1``, zero weight) — invisible to
-    histograms, routing, and root counts — so any block split shards.
-    The result is bit-identical to resident ``_grow_sharded`` growth
-    and to the local planes (the engine parity matrix).
+    histograms, routing, and root counts — so any block split shards;
+    a block whose pad is zero is fed as it is (a memmap view stays a
+    view). DSI counts are integer-valued, so the blocked accumulation is
+    bit-exact for classification: the result equals the resident
+    ``grow_forest`` and ``_grow_sharded`` forests array for array
+    (tests/test_engine.py). Regression channels agree to float rounding.
+    Host-side early exit stops the level loop as soon as every tree's
+    frontier is empty (``config.early_exit`` only gates the device-side
+    ``lax.while_loop``).
 
-    **Checkpointing** mirrors ``grow_forest_streamed``: ``manager``
+    **Checkpointing** mirrors ``grow_forest_checkpointed``: ``manager``
     saves the driver's full inter-level carry (forest, frontier, level
-    plan, per-block slot tables) after each level; ``resume_from``
-    restores the latest carry — slot tables back to their
-    ``P(None, sample_axes)`` sharding — and the level loop continues
-    where it stopped, bit-identically. ``feeder_opts`` forwards
-    retry/backoff/fault-injection knobs to the ``BlockFeeder``;
-    ``quarantined`` block indices are dropped from every sweep.
+    plan, per-block slot tables — see ``_stream_state_like``) after
+    each level; ``resume_from`` restores the newest *CRC-verified*
+    carry (a corrupted or torn newest step is skipped) with its mesh
+    shardings, and the level loop continues where it stopped,
+    producing the bit-identical forest. ``on_level(level, forest)``
+    fires after each completed level's checkpoint.
+
+    **Quarantine.** ``quarantined`` block indices (plus any the feeder's
+    own ``validator`` flags — forward one via ``feeder_opts``, with the
+    retry/backoff/fault-injection knobs) are dropped from every level
+    sweep: never transferred, never routed, never histogrammed. Their
+    slot-table entries stay in the checkpoint carry, so the carry
+    structure — and therefore resume — is independent of which blocks
+    were quarantined.
 
     **Multi-process plane.** With ``runtime`` (a
     ``launch.multiproc.MultiHostMesh``) the same driver runs across
@@ -433,283 +674,116 @@ def grow_forest_streamed_sharded(
     mesh. Checkpoints go through the multi-process manager/restore
     (process-0 manifest, per-host shard leaves).
     """
-    from .api import _stream_setup
-
     sample_axes = tuple(sample_axes)
-    if runtime is not None:
-        if block_sizes is None:
-            raise ValueError(
-                "grow_forest_streamed_sharded(runtime=...) needs "
-                "block_sizes — the global unpadded sizes the host-local "
-                "block slices were cut from"
-            )
-        sizes = [int(n) for n in block_sizes]
-        offsets = np.concatenate([[0], np.cumsum(sizes)])
-        y_np = np.asarray(y)
-        if config.regression:
-            y_np = y_np.astype(np.float32)
-        w_np = np.asarray(weights, dtype=np.float32)
-        local_blocks = list(x_binned)
-        F = local_blocks[0].shape[1]
-    else:
-        feeder0, y_np, w_np, sizes, offsets = _stream_setup(
-            x_binned, y, weights, config, prefetch
+    if mesh is None:
+        mesh = Mesh(
+            np.asarray(jax.devices()[:1]).reshape((1,) * (len(sample_axes) + 1)),
+            sample_axes + (feature_axis,),
         )
-        F = feeder0.blocks[0].shape[1]
+    blocks, y_np, w_np, sizes, offsets = _stream_setup(
+        x_binned, y, weights, config, runtime=runtime, block_sizes=block_sizes,
+    )
+    F = blocks[0].shape[1]
     D = int(np.prod([mesh.shape[a] for a in sample_axes]))
     k, S = config.n_trees, config.frontier
-    B = config.n_bins
-    C = 3 if config.regression else config.n_classes
+    prog = _stream_programs(mesh, config, sample_axes, feature_axis, F)
+    reuse = prog.reuse
 
-    x_sh = NamedSharding(mesh, P(sample_axes, feature_axis))
+    x_spec = P(sample_axes, feature_axis)
+    rep_sh = NamedSharding(mesh, P())
     row_sh = NamedSharding(mesh, P(sample_axes))
     kn_sh = NamedSharding(mesh, P(None, sample_axes))
-    rep_sh = NamedSharding(mesh, P())
-    hist_spec = P(sample_axes, None, None, feature_axis)
 
-    # Sibling-subtraction reuse (config.hist_reuse): per-block partials
-    # scatter into R rank segments instead of S slots — the [D, k, R,
-    # F, B, C] carry AND the per-level combine halve — and the plan
-    # step reconstructs large children from the durable cache. The
-    # cache histogram is feature-sharded exactly like the checkpointed
-    # resident path's.
-    Fl = F // int(mesh.shape[feature_axis])
-    use_rs = (
-        config.hist_reduce == "psum_scatter"
-        and len(sample_axes) == 1 and Fl % D == 0
-    )
-    reuse = resolve_hist_reuse(config, Fl)
-    n_rows = config.max_splits_per_level if reuse else S
-    cache_sh = None
-    if reuse:
-        hist_axes = (feature_axis, sample_axes[0]) if use_rs else feature_axis
-        cache_sh = {
-            "hist": NamedSharding(mesh, P(None, None, hist_axes)),
-            "perm": rep_sh, "parent": rep_sh, "small_right": rep_sh,
-        }
-        cache_specs = {
-            "hist": P(None, None, hist_axes),
-            "perm": P(), "parent": P(), "small_right": P(),
-        }
-
-    from ..data.pipeline import BlockFeeder
+    def place(host, spec):
+        """A host array every process holds in full, on the mesh."""
+        if runtime is not None:
+            return runtime.put_full(np.asarray(host), spec)
+        return jax.device_put(host, NamedSharding(mesh, spec))
 
     pads = [(-n) % D for n in sizes]
     ms = [n + p for n, p in zip(sizes, pads)]       # padded global rows
-    from .api import _channels
-
-    base_dev, w_dev, slot_dev = [], [], []
     if runtime is not None:
-        x_spec = P(sample_axes, feature_axis)
         feeder = BlockFeeder(
-            local_blocks,
-            placement=runtime.block_placement(ms, F, x_spec),
-            prefetch=prefetch, quarantined=quarantined,
-            **(feeder_opts or {}),
+            blocks, placement=runtime.block_placement(ms, F, x_spec),
+            prefetch=prefetch, quarantined=quarantined, **(feeder_opts or {}),
         )
-        for i, m in enumerate(ms):
-            o0 = offsets[i]
-            lo, hi = runtime.local_row_range(m)
-            nreal = max(min(hi, sizes[i]) - lo, 0)   # local non-pad rows
-            yb = np.zeros((hi - lo,), y_np.dtype)
-            yb[:nreal] = y_np[o0 + lo:o0 + lo + nreal]
-            # Channels on the local rows only — _channels is row-wise,
-            # so this is the row slice of the single-process build.
-            ch = np.asarray(_channels(jnp.asarray(yb), config))
-            base_dev.append(runtime.put(
-                ch, (m,) + ch.shape[1:], P(sample_axes),
-                box=[(lo, hi)] + [(0, s) for s in ch.shape[1:]],
-            ))
-            wb = np.zeros((k, hi - lo), np.float32)
-            wb[:, :nreal] = w_np[:, o0 + lo:o0 + lo + nreal]
-            w_dev.append(runtime.put(
-                wb, (k, m), P(None, sample_axes), box=[(0, k), (lo, hi)],
-            ))
-            slot0 = np.zeros((k, hi - lo), np.int32)
-            slot0[:, max(sizes[i] - lo, 0):] = -1    # pad rows stay parked
-            slot_dev.append(runtime.put(
-                slot0, (k, m), P(None, sample_axes), box=[(0, k), (lo, hi)],
-            ))
     else:
         feeder = BlockFeeder(
-            [_pad_rows(b, p) for b, p in zip(feeder0.blocks, pads)],
-            placement=x_sh, prefetch=prefetch, quarantined=quarantined,
-            **(feeder_opts or {}),
+            [_pad_rows(b, p) for b, p in zip(blocks, pads)],
+            placement=NamedSharding(mesh, x_spec), prefetch=prefetch,
+            quarantined=quarantined, **(feeder_opts or {}),
         )
-        for i, p in enumerate(pads):
-            o0, o1 = offsets[i], offsets[i + 1]
-            # Channels built on device by the same _channels every other
-            # plane uses; pad rows are zero-weight + parked, so their
-            # channel content is irrelevant.
-            base_dev.append(_channels(
-                jax.device_put(_pad_rows(y_np[o0:o1], p), row_sh), config,
-            ))
-            w_dev.append(
-                jax.device_put(_pad_rows(w_np[:, o0:o1].T, p).T, kn_sh)
-            )
-            slot0 = np.zeros((k, sizes[i] + p), np.int32)
+
+    # Per-block constants: pinned on device ONCE for the whole growth;
+    # quarantined blocks get none. Pad rows are zero-weight and parked,
+    # so their label channels are irrelevant.
+    base_dev, w_dev = [None] * len(sizes), [None] * len(sizes)
+    slot_dev = []
+    for i, m in enumerate(ms):
+        o0 = offsets[i]
+        if runtime is None:
+            slot0 = np.zeros((k, m), np.int32)
             slot0[:, sizes[i]:] = -1                # pad rows stay parked
             slot_dev.append(jax.device_put(slot0, kn_sh))
-
-    mask_np = (
-        np.ones((k, F), bool) if feature_mask is None
-        else np.asarray(feature_mask, bool)
-    )
-    mask_dev = (
-        runtime.put_full(mask_np, P(None, feature_axis))
-        if runtime is not None
-        else jax.device_put(mask_np, NamedSharding(mesh, P(None, feature_axis)))
-    )
-
-    def make_plane(Fl, mask_loc=None):
-        return MeshPlane(
-            config, Fl, mask_loc,
-            sample_axes=sample_axes, feature_axis=feature_axis,
-        )
-
-    def step_kernel_route(hist_part, xb_loc, base_loc, w_loc, slot_loc,
-                          slot_node, split_rank, scores, small_right=None):
-        h, slot_loc = stream_block_step(
-            hist_part[0], xb_loc, base_loc, w_loc, slot_loc, slot_node,
-            split_rank, scores, config, make_plane(xb_loc.shape[1]),
-            route=True, small_right=small_right,
-        )
-        return h[None], slot_loc
-
-    def step_kernel_first(hist_part, xb_loc, base_loc, w_loc, slot_loc,
-                          slot_node, small_right=None):
-        h, slot_loc = stream_block_step(
-            hist_part[0], xb_loc, base_loc, w_loc, slot_loc, slot_node,
-            None, None, config, make_plane(xb_loc.shape[1]), route=False,
-            small_right=small_right,
-        )
-        return h[None], slot_loc
-
-    data_specs = (hist_spec, P(sample_axes, feature_axis), P(sample_axes),
-                  P(None, sample_axes), P(None, sample_axes), P())
-    sr_specs = (P(),) if reuse else ()
-    step_route = jax.jit(jax.shard_map(
-        step_kernel_route, mesh=mesh,
-        in_specs=data_specs + (P(), P()) + sr_specs,
-        out_specs=(hist_spec, P(None, sample_axes)),
-        check_vma=False,
-    ))
-    step_first = jax.jit(jax.shard_map(
-        step_kernel_first, mesh=mesh,
-        in_specs=data_specs + sr_specs,
-        out_specs=(hist_spec, P(None, sample_axes)),
-        check_vma=False,
-    ))
-
-    split_be = resolve_split_backend(config.split_backend)
-
-    def _root_init(forest, hist_c):
-        # Root counts: any feature's bin marginal of the level-0
-        # histogram (slot/rank row 0) sums to the [k, C] root class
-        # counts (identical on every shard — exact integer sums).
-        root = hist_c[:, 0, 0].sum(axis=1)
-        forest = dataclasses.replace(
-            forest, class_counts=forest.class_counts.at[:, 0].set(root),
-        )
-        if config.regression:
-            forest = dataclasses.replace(
-                forest, value=forest.value.at[:, 0].set(_safe_mean(root)),
-            )
-        return forest
-
-    def make_plan(init: bool):
-        def plan_kernel(hist_part, forest, slot_node, level, mask_loc):
-            plane = make_plane(hist_part.shape[3], mask_loc)
-            hist_c = plane.combine_hist(hist_part[0])
-            if init:
-                forest = _root_init(forest, hist_c)
-            scores_loc, n_loc = level_scores(
-                hist_c, plane.level_mask, regression=config.regression,
-                backend=split_be,
-            )
-            scores, n_node = plane.merge_winners(scores_loc, n_loc)
-            split_rank, is_split, child_base = plan_level(
-                scores, n_node, slot_node, config, level
-            )
-            forest = write_level(
-                forest, slot_node, split_rank, is_split, child_base, scores,
-                config,
-            )
-            return (
-                forest, scores, split_rank,
-                next_frontier(is_split, child_base, config.frontier),
-            )
-
-        def plan_kernel_reuse(hist_part, forest, slot_node, level, mask_loc,
-                              cache):
-            plane = make_plane(hist_part.shape[3], mask_loc)
-            hist_c = plane.combine_hist(hist_part[0])   # packed: half the wire
-            if init:
-                forest = _root_init(forest, hist_c)
-            scores, n_node, hist2, perm = reuse_expand_scores(
-                hist_c, cache, plane.level_mask, config
-            )
-            scores, n_node = plane.merge_winners(scores, n_node)
-            split_rank, is_split, child_base = plan_level(
-                scores, n_node, slot_node, config, level
-            )
-            forest = write_level(
-                forest, slot_node, split_rank, is_split, child_base, scores,
-                config,
-            )
-            parent, small_right = sibling_plan(
-                scores, split_rank, is_split,
-                n_ranks=config.max_splits_per_level,
-                regression=config.regression,
-            )
-            return (
-                forest, scores, split_rank,
-                next_frontier(is_split, child_base, config.frontier),
-                {"hist": hist2, "perm": perm,
-                 "parent": parent, "small_right": small_right},
-            )
-
-        if reuse:
-            return jax.jit(jax.shard_map(
-                plan_kernel_reuse, mesh=mesh,
-                in_specs=(hist_spec, P(), P(), P(), P(None, feature_axis),
-                          cache_specs),
-                out_specs=(P(), P(), P(), P(), cache_specs),
-                check_vma=False,
-            ))
-        return jax.jit(jax.shard_map(
-            plan_kernel, mesh=mesh,
-            in_specs=(hist_spec, P(), P(), P(), P(None, feature_axis)),
-            out_specs=(P(), P(), P(), P()),
-            check_vma=False,
+            if i in feeder.live_blocks:
+                o1 = offsets[i + 1]
+                base_dev[i] = label_channels(
+                    feeder.pin(_pad_rows(y_np[o0:o1], pads[i]), row_sh), config
+                )
+                w_dev[i] = feeder.pin(
+                    _pad_rows(w_np[:, o0:o1].T, pads[i]).T, kn_sh
+                )
+            continue
+        lo, hi = runtime.local_row_range(m)
+        nreal = max(min(hi, sizes[i]) - lo, 0)       # local non-pad rows
+        slot0 = np.zeros((k, hi - lo), np.int32)
+        slot0[:, max(sizes[i] - lo, 0):] = -1        # pad rows stay parked
+        slot_dev.append(runtime.put(
+            slot0, (k, m), P(None, sample_axes), box=[(0, k), (lo, hi)],
         ))
+        if i not in feeder.live_blocks:
+            continue
+        yb = np.zeros((hi - lo,), y_np.dtype)
+        yb[:nreal] = y_np[o0 + lo:o0 + lo + nreal]
+        # Channels on the local rows only — label_channels is row-wise,
+        # so this is the row slice of the single-process build.
+        ch = np.asarray(label_channels(jnp.asarray(yb), config))
+        base_dev[i] = runtime.put(
+            ch, (m,) + ch.shape[1:], P(sample_axes),
+            box=[(lo, hi)] + [(0, s) for s in ch.shape[1:]],
+        )
+        wb = np.zeros((k, hi - lo), np.float32)
+        wb[:, :nreal] = w_np[:, o0 + lo:o0 + lo + nreal]
+        w_dev[i] = runtime.put(
+            wb, (k, m), P(None, sample_axes), box=[(0, k), (lo, hi)],
+        )
 
-    plan_init, plan_next = make_plan(True), make_plan(False)
-
+    mask_dev = (
+        None if feature_mask is None
+        else place(np.asarray(feature_mask, bool), P(None, feature_axis))
+    )
+    hist_shape = (D, k, prog.n_rows, F, config.n_bins,
+                  3 if config.regression else config.n_classes)
     hist0 = (
-        runtime.zeros((D, k, n_rows, F, B, C), hist_spec, jnp.float32)
+        runtime.zeros(hist_shape, prog.hist_spec, jnp.float32)
         if runtime is not None
         else jax.device_put(
-            jnp.zeros((D, k, n_rows, F, B, C), jnp.float32),
-            NamedSharding(mesh, hist_spec),
+            jnp.zeros(hist_shape, jnp.float32),
+            NamedSharding(mesh, prog.hist_spec),
         )
     )
 
     state = None
     if resume_from is not None:
-        from ..checkpoint.checkpoint import restore_latest_valid
-        from .api import _stream_state_like
-
         # The like-template is GLOBAL-shaped: cache width F (the mesh
-        # shards its feature dim per cache_sh on restore).
-        like = _stream_state_like(
-            [n + p for n, p in zip(sizes, pads)], config,
-            F if reuse else 0,
-        )
+        # shards its feature dim per cache_specs on restore).
+        like = _stream_state_like(ms, config, F if reuse else 0)
         shardings = jax.tree_util.tree_map(lambda _: rep_sh, like)
         shardings["slots"] = [kn_sh for _ in like["slots"]]
         if reuse:
-            shardings["hist_cache"] = cache_sh
+            shardings["hist_cache"] = {
+                n: NamedSharding(mesh, s) for n, s in prog.cache_specs.items()
+            }
         if runtime is not None:
             from ..launch.multiproc import restore_latest_valid_multiproc
 
@@ -728,64 +802,34 @@ def grow_forest_streamed_sharded(
     else:
         slot0_np = np.full((k, S), -1, np.int32)
         slot0_np[:, 0] = 0
-        slot_node = (
-            runtime.put_full(slot0_np, P()) if runtime is not None
-            else jax.device_put(jnp.asarray(slot0_np), rep_sh)
-        )
-        forest, scores, split_rank = None, None, None
+        slot_node = place(slot0_np, P())
+        # forest=None: the level-0 plan builds the root node.
+        forest, scores, split_rank, cache = None, None, None, None
         start = 0
-        # Global cache width F — sharded per cache_sh (dim 2).
         if reuse:
-            cache0 = init_hist_cache(config, F)
-            cache = (
-                {n: runtime.put_full(np.asarray(v), cache_specs[n])
-                 for n, v in cache0.items()}
-                if runtime is not None
-                else jax.device_put(cache0, cache_sh)
-            )
-        else:
-            cache = None
+            cache = {
+                n: place(np.asarray(v), prog.cache_specs[n])
+                for n, v in init_hist_cache(config, F).items()
+            }
 
-    def level_sweep(route: bool):
+    def level_sweep():
         hist = hist0
-        sr = ((cache["small_right"],) if reuse else ())
+        small_right = None if cache is None else cache["small_right"]
         for i, xb_b in zip(feeder.live_blocks, feeder.sweep()):
-            if route:
-                hist, slot_dev[i] = step_route(
-                    hist, xb_b, base_dev[i], w_dev[i], slot_dev[i],
-                    slot_node, split_rank, scores, *sr,
-                )
-            else:
-                hist, slot_dev[i] = step_first(
-                    hist, xb_b, base_dev[i], w_dev[i], slot_dev[i], slot_node,
-                    *sr,
-                )
+            hist, slot_dev[i] = prog.step(
+                hist, xb_b, base_dev[i], w_dev[i], slot_dev[i], slot_node,
+                split_rank, scores, small_right,
+            )
         return hist
 
     try:
         for level in range(start, config.max_depth):
             if not np.any(np.asarray(slot_node) >= 0):
-                break
-            hist = level_sweep(route=level > 0)
-            plan = plan_next if forest is not None else plan_init
-            if forest is None:
-                f0 = init_forest(config)
-                forest = (
-                    jax.tree_util.tree_map(
-                        lambda a: runtime.put_full(np.asarray(a), P()), f0
-                    )
-                    if runtime is not None else jax.device_put(f0, rep_sh)
-                )
-            if reuse:
-                forest, scores, split_rank, slot_node, cache = plan(
-                    hist, forest, slot_node, np.int32(level),
-                    mask_dev, cache,
-                )
-            else:
-                forest, scores, split_rank, slot_node = plan(
-                    hist, forest, slot_node, np.int32(level),
-                    mask_dev,
-                )
+                break                               # every frontier is empty
+            forest, scores, split_rank, slot_node, cache = prog.plan(
+                level_sweep(), forest, slot_node, np.int32(level), mask_dev,
+                cache,
+            )
             if manager is not None:
                 manager.maybe_save({
                     "forest": forest, "slot_node": slot_node,
@@ -797,30 +841,7 @@ def grow_forest_streamed_sharded(
                 on_level(level + 1, forest)
 
         if forest is None:          # max_depth == 0: root node only
-            def root_kernel(hist_part):
-                plane = make_plane(hist_part.shape[3])
-                hist_c = plane.combine_hist(hist_part[0])
-                return hist_c[:, 0, 0].sum(axis=1)
-
-            root_fn = jax.jit(jax.shard_map(
-                root_kernel, mesh=mesh, in_specs=(hist_spec,), out_specs=P(),
-                check_vma=False,
-            ))
-            # Host round-trip: the replicated root counts fetch cleanly
-            # on every process, and the .at[].set below then runs on
-            # purely local arrays (eager ops on multi-process global
-            # arrays would raise).
-            root = jnp.asarray(np.asarray(jax.device_get(
-                root_fn(level_sweep(route=False))
-            )))
-            forest = init_forest(config)
-            forest = dataclasses.replace(
-                forest, class_counts=forest.class_counts.at[:, 0].set(root)
-            )
-            if config.regression:
-                forest = dataclasses.replace(
-                    forest, value=forest.value.at[:, 0].set(_safe_mean(root))
-                )
+            forest = prog.root(level_sweep())
     finally:
         feeder.close()
     if runtime is not None:
@@ -866,8 +887,6 @@ def oob_accuracy_streamed_sharded(
     exact-integer sums make that bitwise identical to dropping those
     rows, which is how the single-host path excludes imputed-label
     samples."""
-    from ..data.pipeline import BlockFeeder, stream_blocks
-
     sample_axes = tuple(sample_axes)
     y_np = np.asarray(y)
     w_np = np.asarray(weights, dtype=np.float32)
@@ -1001,8 +1020,6 @@ def predict_streamed_sharded(
     are per-sample, so the blocked sweep is bit-identical to
     ``predict_sharded`` on the full matrix; only one padded block is
     device-resident at a time. Returns [N] labels (host array)."""
-    from ..data.pipeline import BlockFeeder, stream_blocks
-
     sample_axes = tuple(sample_axes)
     blocks = stream_blocks(
         x_binned, sample_block, what="predict_streamed_sharded"
@@ -1118,11 +1135,7 @@ def make_prf_train_fn(
                 jax.random.fold_in(key, _multi_axis_index(sample_axes))
             )
             Nl = xb_loc.shape[0]
-            base_loc = (
-                regression_channels(y_loc)
-                if config.regression
-                else class_channels(y_loc, config.n_classes)
-            )
+            base_loc = label_channels(y_loc, config)
             # Stratified DSI bootstrap (see module docstring).
             w_loc = bootstrap_counts(k_boot, config.n_trees, Nl)
 
@@ -1363,8 +1376,6 @@ def fit_bins_sharded(
     caller recomputes lazily) carries the validator's imputed-cell
     masks, exactly as in ``fit_bins_blocked``.
     """
-    from ..data.pipeline import stream_blocks
-
     n_bins = validate_n_bins(n_bins)
     if max_size is None:
         max_size = DEFAULT_SKETCH_SIZE
@@ -1406,7 +1417,6 @@ def _dimred_streamed_multiproc(
     identical to the single-host sweep. The mask comes back replicated
     and is re-derived host-locally on every process.
     """
-    from ..data.pipeline import BlockFeeder
     from .dimred import select_features
 
     sample_axes = tuple(sample_axes)
@@ -1479,265 +1489,3 @@ def _dimred_streamed_multiproc(
     return np.asarray(select_features(
         gr, rng, n_selected=cfg.n_selected, n_important=cfg.n_important
     ))
-
-
-def train_prf_multiproc(
-    x, y, config: ForestConfig, seed: int = 0, *,
-    runtime=None,
-    checkpoint_dir: Optional[str] = None,
-    checkpoint_every: int = 1,
-    checkpoint_keep: int = 3,
-    resume_from: Optional[str] = None,
-    on_level=None,
-    feeder_opts: Optional[dict] = None,
-    bad_block_policy: Optional[str] = "raise",
-    sample_axes: Sequence[str] = ("data",),
-    feature_axis: str = "model",
-    sketch_max_size: Optional[int] = None,
-):
-    """End-to-end ``train_prf`` across ``jax.distributed`` processes.
-
-    The whole pipeline — integrity screen, bin-edge fitting, binning,
-    DSI bootstrap, dimension reduction, growth, OOB weighting — runs
-    with every process touching only the rows its sample-axis shards
-    own (``x`` is typically an ``np.memmap``; remote rows are never
-    paged in, except that edge fitting reads the full blocks of this
-    process's shard *subset* — the same block partition as
-    ``fit_bins_sharded``). The trained model is **bitwise identical**
-    to the single-process ``train_prf`` on the same ``(x, y, config,
-    seed)``:
-
-    * the per-block validator scans local rows and union-reduces the
-      per-(block, column) bad-cell counts through one exact integer
-      ``psum_hosts``, so every process reaches the same verdict (and
-      the same typed ``DataIntegrityError`` under ``"raise"``); label
-      screening runs on the globally-resident ``y`` identically
-      everywhere;
-    * edges come from per-shard quantile sketches merged bit-exactly;
-    * the bootstrap/feature-mask PRNG draws are process-independent
-      functions of ``seed``;
-    * growth/dimred/OOB accumulate exact integer-valued f32 sums, so
-      shard-order never matters.
-
-    ``checkpoint_dir``/``resume_from`` go through the multi-process
-    checkpoint protocol (process-0 manifest, per-host shard leaves);
-    resuming under a different process count raises
-    ``CheckpointTopologyError``. Regression with ``weighted_voting``
-    is not wired on this plane yet and raises ``NotImplementedError``.
-    ``sketch_max_size`` caps the per-shard quantile summary (wire and
-    host cost of edge fitting scale with it; below the compression
-    threshold edges are exact).
-    """
-    from ..data.pipeline import (
-        BlockIssue, BlockValidator, DataIntegrityError, QuarantineReport,
-    )
-    from ..launch.multiproc import MultiHostMesh, MultiprocCheckpointManager
-    from .api import PRFModel
-    from .binning import apply_bins
-    from .dimred import random_feature_mask
-
-    config = config.resolved(x.shape[1])
-    if config.sample_block <= 0:
-        raise ValueError(
-            "train_prf_multiproc needs config.sample_block > 0 — the "
-            "multi-process plane is streaming-only (each process feeds "
-            "its local rows of every sample block)"
-        )
-    if getattr(x, "ndim", None) != 2:
-        raise ValueError(
-            "train_prf_multiproc needs a 2-D [N, F] array-like source "
-            "(np.memmap / np.ndarray) so every process can slice its own "
-            f"rows; got {type(x).__name__}"
-        )
-    if runtime is None:
-        runtime = MultiHostMesh(
-            sample_axes=sample_axes, feature_axis=feature_axis
-        )
-    mesh = runtime.mesh
-    sample_axes = tuple(sample_axes)
-    D = runtime.n_data_shards
-    N, F = int(x.shape[0]), int(x.shape[1])
-    nb = config.sample_block
-    sizes = [min(nb, N - o) for o in range(0, N, nb)]
-    offsets = np.concatenate([[0], np.cumsum(sizes)])
-    n_blocks = len(sizes)
-    ms = [n + ((-n) % D) for n in sizes]
-    windows = [runtime.local_row_range(m) for m in ms]
-    y_host = np.asarray(y)
-
-    def _local_view(i):
-        """(view of x's local real rows of block i, their count)."""
-        lo, hi = windows[i]
-        o0 = offsets[i]
-        nreal = max(min(hi, sizes[i]) - lo, 0)
-        return x[o0 + lo:o0 + lo + nreal], nreal
-
-    # ---- integrity screen (union-reduced across processes) ------------
-    report = None
-    cell_cols = None
-    label_masks = {}
-    quar = frozenset()
-    if bad_block_policy not in (None, "off"):
-        validator = BlockValidator(
-            bad_block_policy, n_features=F,
-            n_classes=None if config.regression else config.n_classes,
-            regression=config.regression,
-        )
-        counts = np.zeros((n_blocks, F), np.int64)
-        if np.issubdtype(np.asarray(x[:0]).dtype, np.inexact):
-            for i in range(n_blocks):
-                view, nreal = _local_view(i)
-                if nreal:
-                    counts[i] = (~np.isfinite(np.asarray(view))).sum(axis=0)
-        cell_cols = runtime.psum_hosts(counts.ravel()).reshape(n_blocks, F)
-        for i in range(n_blocks):
-            lm = validator._label_mask(y_host[offsets[i]:offsets[i + 1]])
-            if lm.any():
-                label_masks[i] = lm
-        report = QuarantineReport(
-            policy=bad_block_policy, blocks_checked=n_blocks,
-        )
-        for i in range(n_blocks):
-            bad_cells = int(cell_cols[i].sum())
-            bad_labels = int(label_masks[i].sum()) if i in label_masks else 0
-            if not bad_cells and not bad_labels:
-                continue
-            issue = BlockIssue(
-                index=i, reason="nonfinite" if bad_cells else "label",
-                columns=tuple(int(c) for c in np.flatnonzero(cell_cols[i])),
-                bad_cells=bad_cells, bad_labels=bad_labels,
-            )
-            report.issues.append(issue)
-            if bad_block_policy == "raise":
-                raise DataIntegrityError(
-                    issue.describe(), block_index=i,
-                    columns=issue.columns, reason=issue.reason,
-                )
-            report.sanitized_cells += bad_cells
-            report.sanitized_labels += bad_labels
-            if bad_block_policy == "quarantine":
-                report.quarantined.append(i)
-        quar = frozenset(report.quarantined)
-        if len(quar) == n_blocks:
-            raise DataIntegrityError(
-                f"every block quarantined ({n_blocks} of {n_blocks}) — "
-                "nothing left to train on",
-                reason="quarantine",
-            )
-        if label_masks:
-            y_host = y_host.copy()
-            for i, lm in label_masks.items():
-                y_host[offsets[i]:offsets[i + 1]][lm] = 0
-    good = [i for i in range(n_blocks) if i not in quar]
-    flagged = (
-        set() if cell_cols is None
-        else {i for i in range(n_blocks) if cell_cols[i].any()}
-    )
-
-    # ---- bin edges (per-shard sketches over the good blocks) ----------
-    good_views = [x[offsets[i]:offsets[i + 1]] for i in good]
-
-    def _exclude(j):
-        # Lazily recompute the imputed-cell mask of the j-th good block —
-        # only the sketching shard ever pages the full block in.
-        i = good[j]
-        if i not in flagged:
-            return None
-        return ~np.isfinite(np.asarray(good_views[j]))
-
-    edges = fit_bins_sharded(
-        good_views, config.n_bins, mesh,
-        sample_block=nb, sample_axes=sample_axes,
-        max_size=sketch_max_size,
-        exclude_masks=_exclude if flagged else None,
-        runtime=runtime,
-    )
-    edges_dev = jnp.asarray(edges)
-
-    # ---- bin the local rows of every block ----------------------------
-    xb_local = []
-    for i in range(n_blocks):
-        lo, hi = windows[i]
-        xbl = np.zeros((hi - lo, F), np.uint8)
-        if i in quar:
-            xb_local.append(xbl)             # placeholder, never swept
-            continue
-        view, nreal = _local_view(i)
-        if nreal:
-            xb = np.array(apply_bins(jnp.asarray(np.asarray(view)),
-                                     edges_dev))
-            if i in flagged:
-                # apply_bins is element-wise, so binning the local row
-                # slice matches the full-block binning bitwise; imputed
-                # cells are forced to bin 0 exactly like the single-host
-                # trainer.
-                xb[~np.isfinite(np.asarray(view))] = 0
-            xbl[:nreal] = xb
-        xb_local.append(xbl)
-
-    # ---- DSI bootstrap + feature selection (same PRNG everywhere) -----
-    key = jax.random.PRNGKey(seed)
-    k_boot, k_dim = jax.random.split(key)
-    w_np = np.asarray(bootstrap_counts(k_boot, config.n_trees, N))
-    if label_masks:
-        bad_rows = np.zeros(N, dtype=bool)
-        for i, lm in label_masks.items():
-            bad_rows[offsets[i]:offsets[i + 1]][lm] = True
-        w_np = np.where(bad_rows[None, :], 0, w_np)
-
-    feature_mask = None
-    if config.feature_mode == "importance" and not config.regression:
-        feature_mask = _dimred_streamed_multiproc(
-            xb_local, y_host, w_np, config, k_dim, runtime,
-            sizes=sizes, quarantined=sorted(quar), feeder_opts=feeder_opts,
-            sample_axes=sample_axes, feature_axis=feature_axis,
-        )
-    elif config.feature_mode == "random":
-        feature_mask = np.asarray(random_feature_mask(
-            k_dim, n_trees=config.n_trees, n_features=F,
-            n_selected=config.n_selected,
-        ))
-
-    # ---- growth (the runtime-threaded mesh streamed driver) -----------
-    manager = None
-    if checkpoint_dir is not None:
-        manager = MultiprocCheckpointManager(
-            checkpoint_dir, keep=checkpoint_keep,
-            save_interval=checkpoint_every, runtime=runtime,
-        )
-    y_grow = y_host if not config.regression else y_host.astype(np.float32)
-    forest = grow_forest_streamed_sharded(
-        xb_local, y_grow, w_np, config, mesh, feature_mask,
-        sample_axes=sample_axes, feature_axis=feature_axis,
-        manager=manager, resume_from=resume_from, on_level=on_level,
-        feeder_opts=feeder_opts, quarantined=sorted(quar),
-        runtime=runtime, block_sizes=sizes,
-    )
-
-    if config.weighted_voting:
-        if config.regression:
-            raise NotImplementedError(
-                "weighted_voting for regression (OOB R^2) is not wired "
-                "on the multi-process plane yet — set "
-                "weighted_voting=False, or train single-process"
-            )
-        invalid = {}
-        for i, lm in label_masks.items():
-            if i in quar:
-                continue
-            lo, hi = windows[i]
-            nreal = max(min(hi, sizes[i]) - lo, 0)
-            m = np.zeros(hi - lo, bool)
-            m[:nreal] = lm[lo:lo + nreal]
-            if m.any():
-                invalid[i] = m
-        w = oob_accuracy_streamed_sharded(
-            forest, xb_local, y_host, w_np, mesh,
-            sample_axes=sample_axes, feature_axis=feature_axis,
-            feeder_opts=feeder_opts, quarantined=sorted(quar),
-            runtime=runtime, block_sizes=sizes,
-            invalid_masks=invalid or None,
-        )
-        forest = dataclasses.replace(forest, tree_weight=w)
-
-    return PRFModel(forest=forest, bin_edges=edges, quarantine=report)

@@ -17,10 +17,10 @@ a real ``GrowthState`` carry and parameterized by a **collective plane**:
 
 ``forest.grow_forest`` (LocalPlane), ``distributed._grow_sharded``
 (MeshPlane, built in core/distributed.py next to its collectives) and
-the host-streaming ``api.grow_forest_streamed`` driver are thin entry
-points over the same ``plan_level`` / ``write_level`` / ``route_level``
-pieces, so a split decision is computed by exactly one piece of code no
-matter where the data lives.
+the host-streaming ``distributed.grow_forest_streamed`` driver are thin
+entry points over the same ``plan_level`` / ``write_level`` /
+``route_level`` pieces, so a split decision is computed by exactly one
+piece of code no matter where the data lives.
 
 Scheduling upgrades over the fixed-depth scan of the original trainers:
 
@@ -531,7 +531,6 @@ def init_growth_state(
     plane: CollectivePlane,
     *,
     rng: Optional[jnp.ndarray] = None,
-    root_counts: Optional[jnp.ndarray] = None,   # [k, C] precomputed (streaming)
     n_features: Optional[int] = None,            # local-shard F; enables hist_reuse
 ) -> GrowthState:
     """Forest with the root node populated + an empty level-0 frontier.
@@ -542,10 +541,9 @@ def init_growth_state(
     are untouched."""
     k, S = config.n_trees, config.frontier
     forest = init_forest(config)
-    if root_counts is None:
-        root_counts = plane.reduce_root(
-            jnp.einsum("kn,nc->kc", weights, base_channels)
-        )
+    root_counts = plane.reduce_root(
+        jnp.einsum("kn,nc->kc", weights, base_channels)
+    )
     forest = dataclasses.replace(
         forest, class_counts=forest.class_counts.at[:, 0].set(root_counts)
     )

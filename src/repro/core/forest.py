@@ -5,8 +5,8 @@ Training is one thin call into the unified task-DAG growth engine
 collectives — the whole ``[N, F]`` block lives on one device) and runs
 the engine's ``lax.while_loop`` level-step. The mesh-sharded trainer
 (``core/distributed.py``) and the host-streaming out-of-core driver
-(``core.api.grow_forest_streamed``) run the exact same level-step over
-their own planes, so the growth logic exists once.
+(``core.distributed.grow_forest_streamed``) run the exact same level-step
+over their own planes, so the growth logic exists once.
 
 The T_GR/T_NS chunking machinery (``chunked_level_scores``,
 ``fused_level_scores``) and the shared node-pool helpers live in
@@ -29,7 +29,7 @@ from .engine import (  # noqa: F401  (re-exported: training internals)
     grow, grow_checkpointed, grow_with_leaves, init_forest, resolve_hist_reuse,
     reuse_level_task_group,
 )
-from .histograms import class_channels, regression_channels
+from .histograms import label_channels
 from .tracing import scope
 from .types import Forest, ForestConfig
 
@@ -90,11 +90,7 @@ def grow_forest_checkpointed(
     checkpoint finishes with the same trees an uninterrupted run grows
     (tests/test_fault.py kills it at every boundary to pin this).
     """
-    base = (
-        regression_channels(y)
-        if config.regression
-        else class_channels(y, config.n_classes)
-    )
+    base = label_channels(y, config)
     return grow_checkpointed(
         x_binned, base, weights, config, LocalPlane(feature_mask),
         manager=manager, resume_from=resume_from, on_level=on_level,
@@ -106,11 +102,7 @@ def _grow_forest_impl(x_binned, y, weights, config, feature_mask):
     """The grow program: ``(forest, leaves)``, the ``[k, N]`` leaves of
     ``engine.grow_with_leaves`` when the forest will be OOB-weighted,
     else None (and the compiler drops the carry)."""
-    base = (
-        regression_channels(y)
-        if config.regression
-        else class_channels(y, config.n_classes)
-    )
+    base = label_channels(y, config)
     forest, leaves = grow_with_leaves(
         x_binned, base, weights, config, LocalPlane(feature_mask)
     )

@@ -36,9 +36,9 @@ time, so the full ``[tc, S, F, B, C]`` tensor never reaches HBM;
 ``blocked_level_histograms`` is the sample-axis analogue (a resumable
 device-side accumulation over ``[sample_block, F]`` row blocks, used by
 ``ForestConfig.sample_block`` on the resident path). The host-streaming
-data plane (``core.api.grow_forest_streamed`` and the mesh-composed
-``core.distributed.grow_forest_streamed_sharded``) runs the same
-accumulation across HOST-fed blocks instead: one ``level_histograms``
+data plane (``core.distributed.grow_forest_streamed``, on a one-device
+mesh or a real one) runs the same accumulation across HOST-fed blocks
+instead: one ``level_histograms``
 call per block per level inside ``engine.stream_block_step``, summed
 into a device-resident carry. Both orders are exact for integer-valued
 DSI counts (every partial sum is an exact f32 integer below 2**24), so
@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from ..kernels import default_interpret
 from ..kernels.gain_ratio.kernel import multi_tree_hist_pallas
+from .types import ForestConfig
 
 BACKENDS = ("auto", "pallas", "segment_sum")
 
@@ -179,7 +180,7 @@ def blocked_level_histograms(
     Bounds the per-call sample working set to ``sample_block`` rows —
     the device-side half of the sample-block streaming path
     (``ForestConfig.sample_block``); the host-side half is
-    ``core.api.grow_forest_streamed``.
+    ``core.distributed.grow_forest_streamed``.
     """
     N, F = x_binned.shape
     k = weights.shape[0]
@@ -313,3 +314,12 @@ def regression_channels(y: jnp.ndarray) -> jnp.ndarray:
     """[1, y, y^2] -> [N, 3] float32."""
     y = y.astype(jnp.float32)
     return jnp.stack([jnp.ones_like(y), y, y * y], axis=-1)
+
+
+def label_channels(y: jnp.ndarray, config: ForestConfig) -> jnp.ndarray:
+    """The ``[N, C]`` label channels every growth path histograms:
+    ``regression_channels`` for a regression config, else the one-hot
+    ``class_channels``."""
+    if config.regression:
+        return regression_channels(y)
+    return class_channels(y, config.n_classes)

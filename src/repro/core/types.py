@@ -79,8 +79,8 @@ class ForestConfig:
     # ``train_prf`` dispatches the WHOLE pipeline (binning, dimred,
     # growth, OOB weights, prediction) through the streaming data plane
     # when this is > 0 — the host-streaming ``grow_forest_streamed``
-    # driver (core/api.py) feeds blocks of this size from a NumPy/memmap
-    # source with async double-buffered host->device copies
+    # driver (core/distributed.py) feeds blocks of this size from a
+    # NumPy/memmap source with async double-buffered host->device copies
     # (data.pipeline.BlockFeeder), so the full [N, F] matrix is never
     # device-resident.
     sample_block: int = 0
@@ -240,7 +240,7 @@ class GrowthState:
     One value of this pytree fully describes a paused level-synchronous
     training run: ``core.engine.grow`` threads it through a
     ``lax.while_loop`` (early-exit scheduling), and the host-streaming
-    driver (``core.api.grow_forest_streamed``) keeps the same fields
+    driver (``core.distributed.grow_forest_streamed``) keeps the same fields
     across its per-block device calls. Registered as a pytree so it
     round-trips ``jax.jit`` boundaries (see tests/test_engine.py).
     """

@@ -28,7 +28,7 @@ def sample_blocks(
     """Zero-copy ``[Nb, F]`` row views over a host array / ``np.memmap``.
 
     The block-feed API of the out-of-core trainer
-    (``repro.core.api.grow_forest_streamed``): an array source is
+    (``repro.core.grow_forest_streamed``): an array source is
     sliced into ``block_rows``-row views (no copy — memmap blocks are
     only paged in when a block is fed to the device). An explicit
     list/tuple of blocks passes through with ndarray blocks (memmap
@@ -478,8 +478,9 @@ class BlockFeeder:
     One feeder owns the host-side sample blocks for a whole training /
     evaluation run. Two jobs:
 
-    * ``pin(a)`` — one-shot ``jax.device_put`` of a per-block constant
-      (``y``, DSI weights, channel matrices): uploaded ONCE and kept
+    * ``pin(a, sharding=None)`` — one-shot ``jax.device_put`` of a
+      per-block constant (``y``, DSI weights, channel matrices) under
+      ``sharding`` or ``placement``: uploaded ONCE and kept
       device-resident for every subsequent level sweep, instead of
       re-fed per level.
     * ``sweep()`` — yield device copies of the blocks in order, with a
@@ -490,9 +491,9 @@ class BlockFeeder:
       producer's ``device_put`` genuinely overlap.
 
     ``placement`` is anything ``jax.device_put`` accepts as a target —
-    a device for the single-host driver, or a ``NamedSharding`` so each
+    a device for the single-host carriers, or a ``NamedSharding`` so each
     mesh shard receives its (sample x feature) slice of every block
-    (the mesh-streamed path, ``distributed.grow_forest_streamed_sharded``).
+    (the streamed growth driver, ``distributed.grow_forest_streamed``).
 
     **Fault tolerance.** Every host->device transfer (``pin`` and each
     sweep block) runs through a bounded retry loop: a ``retryable``
@@ -579,24 +580,30 @@ class BlockFeeder:
     def __len__(self) -> int:
         return len(self.blocks)
 
-    def _put(self, host_array, site: str, index: Optional[int] = None):
-        """One host->device transfer under the bounded retry policy."""
+    def _put(
+        self, host_array, site: str, index: Optional[int] = None,
+        placement: Any = None,
+    ):
+        """One host->device transfer under the bounded retry policy, to
+        ``placement`` (the feeder's own when ``None``)."""
         import jax
 
+        if placement is None:
+            placement = self.placement
         self._last_site = site
         attempt = 0
         while True:
             try:
                 if self.fault_hook is not None:
                     self.fault_hook(site)
-                if callable(self.placement):
+                if callable(placement):
                     # Multi-process placement: a callback building the
                     # global device array from this process's host-local
                     # rows (needs the block index for its row offset).
-                    return self.placement(host_array, index)
-                if self.placement is None:
+                    return placement(host_array, index)
+                if placement is None:
                     return jax.device_put(host_array)
-                return jax.device_put(host_array, self.placement)
+                return jax.device_put(host_array, placement)
             except self.retryable as e:
                 attempt += 1
                 if attempt > self.max_retries:
@@ -610,9 +617,10 @@ class BlockFeeder:
                     self.max_backoff,
                 ))
 
-    def pin(self, host_array):
-        """Pin one host array on device (respecting ``placement``)."""
-        return self._put(host_array, "pin")
+    def pin(self, host_array, sharding: Any = None):
+        """Pin one host array on device, under ``sharding`` (the
+        feeder's ``placement`` when ``None``)."""
+        return self._put(host_array, "pin", placement=sharding)
 
     def sweep(self) -> Iterator[Any]:
         """Yield the *live* blocks as device arrays, prefetch-deep.

@@ -21,6 +21,7 @@ import pytest
 
 from repro.core import ForestConfig, GrowthState, grow_forest_streamed
 from repro.core.binning import bin_dataset
+from repro.core.dimred import random_feature_mask
 from repro.core.dsi import bootstrap_counts
 from repro.core.engine import init_growth_state, LocalPlane
 from repro.core.forest import chunked_level_scores, grow_forest
@@ -95,6 +96,26 @@ def test_streamed_blocks_match_resident(grow_case, prefetch):
     _assert_forests_equal(
         f_st, _grow(xb, y, w, cfg), f"streamed blocks prefetch={prefetch}"
     )
+
+
+@pytest.mark.parametrize("hist_reuse", ["off", "on"])
+def test_streamed_masked_blocks_match_resident(grow_case, hist_reuse):
+    """A random per-tree feature mask through the one-device streamed
+    driver (mesh=None) == the resident masked forest, bitwise, with
+    sibling-histogram reuse off and on."""
+    xb, y, w, cfg = grow_case
+    cfg = dataclasses.replace(cfg, hist_reuse=hist_reuse)
+    mask = np.asarray(random_feature_mask(
+        jax.random.PRNGKey(5), n_trees=cfg.n_trees,
+        n_features=xb.shape[1], n_selected=6,
+    ))
+    assert not mask.all() and mask.any(axis=1).all()
+    f_st = grow_forest_streamed(np.array_split(xb, 5), y, w, cfg, mask)
+    f_rs = grow_forest(
+        jnp.asarray(xb), jnp.asarray(y), jnp.asarray(w), cfg,
+        jnp.asarray(mask),
+    )
+    _assert_forests_equal(f_st, f_rs, f"streamed mask reuse={hist_reuse}")
 
 
 def test_streamed_rejects_empty_block_sequence(grow_case):
@@ -347,7 +368,7 @@ def test_mesh_plane_matches_local_bitwise():
         from repro.core import ForestConfig
         from repro.core.binning import bin_dataset
         from repro.core.distributed import (
-            _grow_sharded, grow_forest_streamed_sharded,
+            _grow_sharded, grow_forest_streamed,
             oob_accuracy_streamed_sharded, predict_streamed_sharded,
         )
         from repro.core.dsi import bootstrap_counts
@@ -395,9 +416,9 @@ def test_mesh_plane_matches_local_bitwise():
                         err_msg=f"{n} {hist_reduce} early={early}")
             # Mesh x streaming: host blocks fed into the same collective
             # plane == the local resident forest, bit-for-bit.
-            f_ms = grow_forest_streamed_sharded(
+            f_ms = grow_forest_streamed(
                 blocks, y_np, w_np, dataclasses.replace(cfg0,
-                hist_reduce=hist_reduce), mesh)
+                hist_reduce=hist_reduce), mesh=mesh)
             f_loc = grow_forest(xb_dev, y_dev, w, cfg0)
             for n in ARRS:
                 np.testing.assert_array_equal(

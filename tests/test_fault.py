@@ -133,7 +133,7 @@ def test_resume_after_crash_bit_identical_mesh():
         from repro.core import ForestConfig
         from repro.core.binning import bin_dataset
         from repro.core.distributed import (
-            grow_forest_streamed_sharded, grow_sharded_checkpointed,
+            grow_forest_streamed, grow_sharded_checkpointed,
         )
         from repro.core.dsi import bootstrap_counts
         from repro.core.forest import grow_forest
@@ -184,8 +184,8 @@ def test_resume_after_crash_bit_identical_mesh():
             xb, y_np, w, cfg, mesh, **kw), "mesh-resident")
         cfgs = ForestConfig(n_trees=6, max_depth=4, n_bins=16, n_classes=3,
                             feature_mode="all", sample_block=170).resolved(16)
-        drill(lambda **kw: grow_forest_streamed_sharded(
-            xb, y_np, w, cfgs, mesh, **kw), "mesh-streamed")
+        drill(lambda **kw: grow_forest_streamed(
+            xb, y_np, w, cfgs, mesh=mesh, **kw), "mesh-streamed")
         print("MESH_RESUME_OK")
     """)
     out = subprocess.run(

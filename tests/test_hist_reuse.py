@@ -302,7 +302,7 @@ def test_mesh_reuse_parity_and_resume():
         from repro.core import ForestConfig
         from repro.core.binning import bin_dataset
         from repro.core.distributed import (
-            _grow_sharded, grow_forest_streamed_sharded,
+            _grow_sharded, grow_forest_streamed,
         )
         from repro.core.dsi import bootstrap_counts
         from repro.core.forest import grow_forest
@@ -347,7 +347,7 @@ def test_mesh_reuse_parity_and_resume():
             ))(xb_dev, y_dev, w)
             check(f_mesh, f"resident {hist_reduce}")
             cfg_st = dataclasses.replace(cfg, sample_block=170)
-            check(grow_forest_streamed_sharded(xb, y_np, w_np, cfg_st, mesh),
+            check(grow_forest_streamed(xb, y_np, w_np, cfg_st, mesh=mesh),
                   f"streamed {hist_reduce}")
         print("MESH_REUSE_PARITY_OK")
 
@@ -362,15 +362,15 @@ def test_mesh_reuse_parity_and_resume():
 
         d = tempfile.mkdtemp()
         try:
-            grow_forest_streamed_sharded(
-                xb, y_np, w_np, cfg_st, mesh,
+            grow_forest_streamed(
+                xb, y_np, w_np, cfg_st, mesh=mesh,
                 manager=CheckpointManager(d, keep=3, save_interval=1),
                 on_level=boom)
             raise AssertionError("kill did not fire")
         except Kill:
             pass
-        check(grow_forest_streamed_sharded(xb, y_np, w_np, cfg_st, mesh,
-                                           resume_from=d),
+        check(grow_forest_streamed(xb, y_np, w_np, cfg_st, mesh=mesh,
+                                   resume_from=d),
               "streamed resume")
         print("MESH_REUSE_RESUME_OK")
     """)

@@ -119,7 +119,7 @@ WORKER = textwrap.dedent("""
             from repro.core.api import train_prf
             model = train_prf(x, y, cfg, seed=3, **kw)
         else:
-            from repro.core.distributed import train_prf_multiproc
+            from repro.core.api import train_prf_multiproc
             if scenario == "mem":
                 import tracemalloc
                 # First run warms the jit caches: tracing/compile
